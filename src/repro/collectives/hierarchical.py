@@ -25,7 +25,7 @@ import re
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.analysis.roofline import _SHAPE_RE, _shape_bytes
 
@@ -40,13 +40,13 @@ def hierarchical_allreduce(x, mesh: Mesh, pod_axis: str = "pod", fast_axis: str 
     if pod_axis not in names:
         return shard_map(
             lambda v: jax.lax.psum(v, fast_axis),
-            mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False,
+            mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False,
         )(x)
     fast = mesh.shape[fast_axis]
     if x.shape[0] % fast:
         return shard_map(
             lambda v: jax.lax.psum(v, (pod_axis, fast_axis)),
-            mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False,
+            mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False,
         )(x)
 
     def body(v):
@@ -57,7 +57,7 @@ def hierarchical_allreduce(x, mesh: Mesh, pod_axis: str = "pod", fast_axis: str 
         # step 3: fast-tier all-gather
         return jax.lax.all_gather(shard, fast_axis, axis=0, tiled=True)
 
-    return shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False)(x)
+    return shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)(x)
 
 
 def tiered_collective_bytes(hlo_text: str, pod_size: int) -> dict[str, int]:

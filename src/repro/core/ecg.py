@@ -62,6 +62,8 @@ import dataclasses
 import warnings
 from typing import Callable
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 
@@ -72,6 +74,61 @@ from repro.core.methods import MethodContext, get_method
 from repro.core.methods.base import _apply_vec, _chol_inv_apply  # noqa: F401  (back-compat re-exports)
 from repro.kernels.block_update.ops import ecg_tail
 from repro.kernels.fused_gram.ops import fused_gram
+
+
+class _ConstArgJit:
+    """``jax.jit(fn)`` with the device arrays ``fn``'s closures hold passed
+    to the compiled program as arguments.
+
+    ``jax.jit`` embeds closed-over arrays in the program as constants; the
+    solve loop closes over the whole operator (Block-ELL tiles, exchange
+    plans, preconditioner factors), which at deployment size is gigabytes
+    of HLO literal to hash, fold and compile.  One trace per argument shape
+    finds those arrays; the jitted program then takes them as inputs.
+    """
+
+    def __init__(self, fn):
+        self._fn = fn
+        self._entries: dict = {}
+
+    def _entry(self, args):
+        leaves, tree = jax.tree.flatten(args)
+        key = (tree, tuple((tuple(x.shape), np.dtype(x.dtype)) for x in leaves))
+        entry = self._entries.get(key)
+        if entry is None:
+            closed, out_shape = jax.make_jaxpr(self._fn, return_shape=True)(*args)
+            out_tree = jax.tree.structure(out_shape)
+            lift = [isinstance(c, jax.Array) for c in closed.consts]
+
+            def run(lifted, *a):
+                it = iter(lifted)
+                consts = [next(it) if f else c for c, f in zip(closed.consts, lift)]
+                out = jax.core.eval_jaxpr(closed.jaxpr, consts, *jax.tree.leaves(a))
+                return jax.tree.unflatten(out_tree, out)
+
+            lifted = [c for c, f in zip(closed.consts, lift) if f]
+            entry = self._entries[key] = (jax.jit(run), lifted)
+        return entry
+
+    def __call__(self, *args):
+        fn, lifted = self._entry(args)
+        return fn(lifted, *args)
+
+    def lower(self, *args):
+        fn, lifted = self._entry(args)
+        return fn.lower(lifted, *args)
+
+
+def jit_solve(go):
+    """Compile a solve program: operator arrays as arguments, and float32
+    matmuls at full precision (the TPU default rounds them to bfloat16,
+    which the Gram/Cholesky steps of ECG cannot absorb)."""
+
+    def traced(*args):
+        with jax.default_matmul_precision("highest"):
+            return go(*args)
+
+    return _ConstArgJit(traced)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -360,9 +417,9 @@ def _ecg_solve(
     x0 = jnp.zeros_like(b) if x0 is None else x0
     if resume_state is not None:
         # continue a width-segmented solve from the carried loop state
-        out = jax.jit(runner.run)(dict(resume_state))
+        out = jit_solve(runner.run)(dict(resume_state))
     else:
-        out = jax.jit(lambda b_, x0_: runner.run(runner.init(b_, x0_)))(b, x0)
+        out = jit_solve(lambda b_, x0_: runner.run(runner.init(b_, x0_)))(b, x0)
     return finalize_result(
         out, x0=x0, t=t, tol=tol, policy=policy, selection=selection
     )

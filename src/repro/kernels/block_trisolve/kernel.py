@@ -25,30 +25,36 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _kernel(l_ref, x_ref, out_ref):
     l = l_ref[0]  # (bs, bs) lower factor
     x = x_ref[0]  # (bs, t)
     bs = l.shape[0]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
+    # row ids down the sublanes, column ids along the lanes (two iotas: a
+    # transposed (bs, 1) mask would be a 1-lane transpose Mosaic refuses)
+    row_id = jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
+    col_id = jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
 
     def fwd(i, y):
-        row_mask = iota == i  # (bs, 1)
+        row_mask = row_id == i  # (bs, 1)
         row = jnp.sum(jnp.where(row_mask, l, 0.0), axis=0, keepdims=True)  # L[i, :]
         xi = jnp.sum(jnp.where(row_mask, x, 0.0), axis=0, keepdims=True)   # x[i, :]
-        lii = jnp.sum(jnp.where(row_mask.T, row, 0.0))                     # L[i, i]
-        yi = (xi - jnp.dot(row, y, preferred_element_type=y.dtype)) / lii
+        lii = jnp.sum(jnp.where(col_id == i, row, 0.0))                    # L[i, i]
+        yi = (xi - jnp.dot(row, y, precision=_HIGHEST,
+                            preferred_element_type=y.dtype)) / lii
         return jnp.where(row_mask, yi, y)
 
     y = jax.lax.fori_loop(0, bs, fwd, jnp.zeros_like(x))
 
     def bwd(j, z):
         i = bs - 1 - j
-        row_mask = iota == i
-        col = jnp.sum(jnp.where(row_mask.T, l, 0.0), axis=1, keepdims=True)  # L[:, i]
+        row_mask = row_id == i
+        col = jnp.sum(jnp.where(col_id == i, l, 0.0), axis=1, keepdims=True)  # L[:, i]
         yi = jnp.sum(jnp.where(row_mask, y, 0.0), axis=0, keepdims=True)
         lii = jnp.sum(jnp.where(row_mask, col, 0.0))
-        zi = (yi - jnp.dot(col.T, z, preferred_element_type=z.dtype)) / lii
+        zi = (yi - jnp.sum(col * z, axis=0, keepdims=True)) / lii  # L[:, i]ᵀ z
         return jnp.where(row_mask, zi, z)
 
     out_ref[0] = jax.lax.fori_loop(0, bs, bwd, jnp.zeros_like(x))

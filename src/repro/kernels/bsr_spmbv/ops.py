@@ -19,43 +19,69 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.sparse.csr import BSRMatrix, CSRMatrix, csr_to_bsr
-from repro.kernels.bsr_spmbv.kernel import bsr_spmbv_pallas
+from repro.kernels.bsr_spmbv.kernel import LANES, bsr_spmbv_pallas
 from repro.kernels.bsr_spmbv.ref import bsr_spmbv_ref
 from repro.kernels.dispatch import resolve_dispatch
 
 
+def lane_major(tiles: np.ndarray) -> np.ndarray:
+    """Tiles ``(nbr, kmax, br, bc)`` -> the kernel's lane-major Block-ELL
+    ``(nbg, kmax*bc, br, LANES)`` (block rows padded to whole lane groups
+    with zero tiles)."""
+    nbr, kmax, br, bc = tiles.shape
+    nbg = max(1, -(-nbr // LANES))
+    tiles = np.pad(tiles, ((0, nbg * LANES - nbr), (0, 0), (0, 0), (0, 0)))
+    return (
+        tiles.reshape(nbg, LANES, kmax, br, bc)
+        .transpose(0, 2, 4, 3, 1)
+        .reshape(nbg, kmax * bc, br, LANES)
+    )
+
+
 def bsr_to_block_ell(b: BSRMatrix, kmax: int | None = None):
-    """BSR -> Block-ELL (fixed tiles per block row; zero-padded)."""
+    """BSR -> lane-major Block-ELL tiles ``(nbg, kmax*bc, br, LANES)`` +
+    ``(nbr, kmax)`` block-column ids (fixed tiles per block row;
+    zero-padded)."""
     nbr = b.n_block_rows
     indptr = np.asarray(b.block_indptr)
     per_row = np.diff(indptr)
     kmax = int(per_row.max()) if kmax is None else kmax
     br, bc = b.block_shape
-    blocks = np.zeros((nbr, kmax, br, bc), dtype=np.asarray(b.blocks).dtype)
-    indices = np.zeros((nbr, kmax), dtype=np.int32)
     src_blocks = np.asarray(b.blocks)
-    src_idx = np.asarray(b.block_indices)
-    for i in range(nbr):
-        s, e = indptr[i], indptr[i + 1]
-        blocks[i, : e - s] = src_blocks[s:e]
-        indices[i, : e - s] = src_idx[s:e]
-    return jnp.asarray(blocks), jnp.asarray(indices)
+    brow = np.repeat(np.arange(nbr), per_row)
+    slot = np.arange(len(brow)) - indptr[brow]
+    tiles = np.zeros((nbr, kmax, br, bc), dtype=src_blocks.dtype)
+    indices = np.zeros((nbr, kmax), dtype=np.int32)
+    tiles[brow, slot] = src_blocks
+    indices[brow, slot] = np.asarray(b.block_indices)
+    return jnp.asarray(lane_major(tiles)), jnp.asarray(indices)
 
 
 def block_ell_from_csr(a: CSRMatrix, br: int, bc: int):
     return bsr_to_block_ell(csr_to_bsr(a, br, bc))
 
 
+def _tile_keys(indptr, indices, n_rows: int, n_cols: int, br: int, bc: int):
+    """Tile key ``block_row * nbc + block_col`` of every nonzero, and the
+    sorted distinct keys.  CSR rows list their columns in order, so a
+    tile's nonzeros come in runs: only the run heads are sorted."""
+    nnz = int(indptr[min(n_rows, len(indptr) - 1)])
+    rows = np.repeat(np.arange(n_rows, dtype=np.int64), np.diff(indptr[: n_rows + 1]))
+    nbc = (n_cols + bc - 1) // bc
+    key = (rows // br) * nbc + indices[:nnz] // bc
+    head = np.ones(nnz, bool)
+    head[1:] = key[1:] != key[:-1]
+    return rows, key, head, np.unique(key[head])
+
+
 def count_block_ell_tiles(indptr, indices, n_rows: int, n_cols: int, br: int, bc: int) -> int:
     """Max distinct (br x bc) tiles in any block row of a raw-CSR matrix."""
     indptr = np.asarray(indptr, dtype=np.int64)
     indices = np.asarray(indices, dtype=np.int64)
-    nnz = int(indptr[min(n_rows, len(indptr) - 1)])
-    if nnz == 0:
+    if int(indptr[min(n_rows, len(indptr) - 1)]) == 0:
         return 0
-    rows = np.repeat(np.arange(n_rows, dtype=np.int64), np.diff(indptr[: n_rows + 1]))
     nbc = (n_cols + bc - 1) // bc
-    tiles = np.unique((rows // br) * nbc + indices[:nnz] // bc)
+    tiles = _tile_keys(indptr, indices, n_rows, n_cols, br, bc)[3]
     return int(np.bincount(tiles // nbc).max())
 
 
@@ -63,40 +89,44 @@ def csr_arrays_to_block_ell(
     indptr, indices, data, n_rows: int, n_cols: int, br: int, bc: int,
     nbr: int, kmax: int,
 ):
-    """Raw CSR arrays -> Block-ELL with caller-fixed (nbr, kmax) padding.
+    """Raw CSR arrays -> lane-major Block-ELL with caller-fixed (nbr, kmax)
+    padding.
 
-    The caller fixes ``nbr``/``kmax`` so per-rank conversions can be stacked
-    into one (p, nbr, kmax, br, bc) device array; unused tiles stay zero with
+    Returns ``(blocks (nbg, kmax*bc, br, LANES), ell_idx (nbr, kmax))``
+    (see :mod:`repro.kernels.bsr_spmbv.kernel` for the layout): tile slot k
+    of block row ``g*LANES + l`` occupies ``blocks[g, k*bc:(k+1)*bc, :, l]``,
+    slots filled in ascending block-column order.  The caller fixes
+    ``nbr``/``kmax`` so per-rank conversions can be stacked into one
+    (p, nbg, kmax*bc, br, LANES) device array; unused slots stay zero with
     block-column id 0 (safe: zero tiles contribute nothing).
     """
     indptr = np.asarray(indptr, dtype=np.int64)
     indices = np.asarray(indices, dtype=np.int64)
     data = np.asarray(data)
-    blocks = np.zeros((nbr, kmax, br, bc), dtype=data.dtype)
+    nbg = max(1, -(-nbr // LANES))
+    kbc = kmax * bc
+    blocks = np.zeros((nbg, kbc, br, LANES), dtype=data.dtype)
     ell_idx = np.zeros((nbr, kmax), dtype=np.int32)
     nnz = int(indptr[min(n_rows, len(indptr) - 1)])
     if nnz == 0:
         return blocks, ell_idx
-    rows = np.repeat(np.arange(n_rows, dtype=np.int64), np.diff(indptr[: n_rows + 1]))
     nbc = (n_cols + bc - 1) // bc
+    rows, key, head, uniq = _tile_keys(indptr, indices, n_rows, n_cols, br, bc)
+    # each tile's slot within its block row (uniq is sorted: rows grouped)
+    t_row = uniq // nbc
+    t_slot = np.arange(len(uniq)) - np.searchsorted(t_row, t_row)
+    if len(uniq) and int(t_slot.max()) >= kmax:
+        bad = int(t_row[np.argmax(t_slot)])
+        raise ValueError(f"block row {bad} overflows kmax={kmax}")
+    ell_idx[t_row, t_slot] = (uniq % nbc).astype(np.int32)
+    # slot of every nonzero: look up each run head, repeat over its run
+    run_slot = t_slot[np.searchsorted(uniq, key[head])]
+    starts = np.flatnonzero(head)
+    slot = np.repeat(run_slot, np.diff(np.append(starts, nnz)))
     brow = rows // br
-    bcol = indices[:nnz] // bc
-    key = brow * nbc + bcol
-    order = np.argsort(key, kind="stable")
-    key_s = key[order]
-    uniq, starts = np.unique(key_s, return_index=True)
-    ends = np.append(starts[1:], len(key_s))
-    r_in = (rows % br)[order]
-    c_in = (indices[:nnz] % bc)[order]
-    d_s = data[:nnz][order]
-    slot = np.zeros(nbr, dtype=np.int64)
-    for u, s, e in zip(uniq, starts, ends):
-        bi, bj = int(u // nbc), int(u % nbc)
-        k = slot[bi]
-        assert k < kmax, f"block row {bi} overflows kmax={kmax}"
-        ell_idx[bi, k] = bj
-        blocks[bi, k, r_in[s:e], c_in[s:e]] = d_s[s:e]
-        slot[bi] += 1
+    kc = slot * bc + indices[:nnz] % bc
+    flat = (((brow // LANES) * kbc + kc) * br + rows % br) * LANES + brow % LANES
+    blocks.reshape(-1)[flat] = data[:nnz]
     return blocks, ell_idx
 
 
@@ -117,8 +147,7 @@ def block_ell_meta(a: CSRMatrix, br: int, bc: int) -> dict:
     n_pad = (n + br - 1) // br * br
     m_pad = (m + bc - 1) // bc * bc
     nbr, nbc = n_pad // br, m_pad // bc
-    rows = np.repeat(np.arange(n, dtype=np.int64), np.diff(indptr))
-    tiles = np.unique((rows // br) * nbc + indices // bc)
+    tiles = _tile_keys(indptr, indices, n, m, br, bc)[3]
     per_row = np.bincount((tiles // nbc).astype(np.int64), minlength=nbr)
     kmax = int(per_row.max()) if len(tiles) else 0
     return dict(

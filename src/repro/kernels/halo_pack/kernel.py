@@ -6,19 +6,21 @@ issued one XLA gather and one scatter per *step*; these kernels assemble the
 whole phase in two dispatches:
 
 * ``halo_pack`` — gather: ``out[i] = src[idx[i]]``.  Scalar-prefetched slot
-  indices drive the ``index_map`` of the source operand (the same pattern as
-  the Block-ELL V operand in ``kernels/bsr_spmbv``), so each packed row
-  streams HBM → VMEM exactly once, in send-buffer order — the buffer the
-  ppermute rounds then slice is contiguous by construction.
+  indices drive the ``index_map`` of the source operand (one phase's slots
+  fit SMEM easily), so each packed row streams HBM → VMEM exactly once, in
+  send-buffer order — the buffer the ppermute rounds then slice is
+  contiguous by construction.
 * ``halo_unpack`` — scatter: ``dst[pos[i]] = buf[i]``, with ``dst`` aliased
   to the output so slots the phase does not write keep their prior contents
   (earlier phases' deliveries).  Out-of-range positions are pre-clamped by
   the plan to the trailing dump slot, so every program writes a valid block.
 
-Row blocks are (1, w) with w = t_active/col_split — narrow for the lane
-width, but the packed layout is what buys the win: the per-phase dispatch
-count is O(1) instead of O(steps), and the ppermute payload is exactly the
-active-width bytes.
+Rows move as (1, 1, w) blocks of an (m, 1, w) view, w = t_active/col_split:
+a block's last two dimensions must be multiples of (8, 128) or span the
+array, and a (1, w) block of an (m, w) array is neither.  Narrow for the
+lane width, but the packed layout is what buys the win: the per-phase
+dispatch count is O(1) instead of O(steps), and the ppermute payload is
+exactly the active-width bytes.
 """
 
 from __future__ import annotations
@@ -40,18 +42,18 @@ def _pack_kernel(idx_ref, src_ref, out_ref):
 def halo_pack_pallas(src, idx, *, interpret: bool = False):
     """src (m, w); idx (c,) int32 -> packed (c, w) = src[idx]."""
     c = idx.shape[0]
-    w = src.shape[1]
+    m, w = src.shape
     return pl.pallas_call(
         _pack_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(c,),
-            in_specs=[pl.BlockSpec((1, w), lambda i, idx: (idx[i], 0))],
-            out_specs=pl.BlockSpec((1, w), lambda i, idx: (i, 0)),
+            in_specs=[pl.BlockSpec((1, 1, w), lambda i, idx: (idx[i], 0, 0))],
+            out_specs=pl.BlockSpec((1, 1, w), lambda i, idx: (i, 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((c, w), src.dtype),
+        out_shape=jax.ShapeDtypeStruct((c, 1, w), src.dtype),
         interpret=interpret,
-    )(idx, src)
+    )(idx, src.reshape(m, 1, w)).reshape(c, w)
 
 
 def _unpack_kernel(pos_ref, dst_ref, buf_ref, out_ref):
@@ -74,12 +76,12 @@ def halo_unpack_pallas(dst, buf, pos, *, interpret: bool = False):
             num_scalar_prefetch=1,
             grid=(c,),
             in_specs=[
-                pl.BlockSpec((1, w), lambda i, pos: (pos[i], 0)),
-                pl.BlockSpec((1, w), lambda i, pos: (i, 0)),
+                pl.BlockSpec((1, 1, w), lambda i, pos: (pos[i], 0, 0)),
+                pl.BlockSpec((1, 1, w), lambda i, pos: (i, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, w), lambda i, pos: (pos[i], 0)),
+            out_specs=pl.BlockSpec((1, 1, w), lambda i, pos: (pos[i], 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((m, w), dst.dtype),
+        out_shape=jax.ShapeDtypeStruct((m, 1, w), dst.dtype),
         input_output_aliases={1: 0},  # dst (first post-prefetch operand) -> out
         interpret=interpret,
-    )(pos, dst, buf)
+    )(pos, dst.reshape(m, 1, w), buf.reshape(c, 1, w)).reshape(m, w)

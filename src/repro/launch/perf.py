@@ -142,13 +142,14 @@ def run_ecg_sweep(out_path: Path, only: str | None = None):
     import numpy as np
 
     from repro.analysis.ecg_bench import kernel_vs_oracle, overlap_vs_blocking_sweep
+    from repro.launch.runtime import solver_dtype
     from repro.sparse import dg_laplace_2d
 
-    jax.config.update("jax_enable_x64", True)
+    dtype = solver_dtype()
     mesh = jax.sharding.Mesh(
         np.array(jax.devices()[:8]).reshape(2, 4), ("node", "proc")
     )
-    a = dg_laplace_2d((16, 12), block=8)
+    a = dg_laplace_2d((16, 12), block=8, dtype=dtype)
     rows = overlap_vs_blocking_sweep(a, mesh, ts=(4, 8)) + kernel_vs_oracle()
     if only:
         rows = [r for r in rows if only in r["name"]]

@@ -30,26 +30,28 @@ p50/p95/p99 per-request latency for whichever policy ran.
 from __future__ import annotations
 
 import argparse
-import os
-import sys
 import time
 
 
-def build_trace(requests: int, dups: int, scale: int, seed: int = 0):
-    """(operators, [(op_index, rhs)]) — shuffled arrival, seeded dups."""
+def build_trace(requests: int, dups: int, scale: int, seed: int = 0,
+                dtype=None):
+    """(operators, [(op_index, rhs)]) — shuffled arrival, seeded dups.
+    ``dtype`` (default float64) is the operators' and right-hand sides'."""
     import numpy as np
 
     from repro.sparse import aniso_laplace_2d, dg_laplace_2d, fd_laplace_2d
 
+    dtype = np.float64 if dtype is None else dtype
     ops = [
-        ("fd2d", fd_laplace_2d(3 * scale)),
-        ("aniso2d", aniso_laplace_2d(2 * scale, eps=0.01)),
-        ("dg2d", dg_laplace_2d((scale, scale), block=4)),
+        ("fd2d", fd_laplace_2d(3 * scale, dtype=dtype)),
+        ("aniso2d", aniso_laplace_2d(2 * scale, eps=0.01, dtype=dtype)),
+        ("dg2d", dg_laplace_2d((scale, scale), block=4, dtype=dtype)),
     ]
     rng = np.random.default_rng(seed)
     fresh = requests - dups
     trace = [
-        (int(i % len(ops)), rng.standard_normal(ops[i % len(ops)][1].shape[0]))
+        (int(i % len(ops)),
+         rng.standard_normal(ops[i % len(ops)][1].shape[0]).astype(dtype))
         for i in range(fresh)
     ]
     for i in range(dups):  # duplicate payloads of earlier requests
@@ -70,14 +72,19 @@ def main():
                     help="operator size knob (rows grow ~quadratically)")
     ap.add_argument("--t", default="4",
                     help="enlarging factor of the solver template, or 'auto'")
-    ap.add_argument("--tol", type=float, default=1e-8)
+    ap.add_argument("--tol", type=float, default=None,
+                    help="residual tolerance (default: 1e-8 in float64, "
+                         "1e-5 in float32)")
     ap.add_argument("--max-batch", type=int, default=8)
     ap.add_argument("--max-pending", type=int, default=256)
     ap.add_argument("--cache-dir", default=None,
                     help="warm-start cache directory (persists tuning)")
     ap.add_argument("--devices", type=int, default=0,
-                    help="force host devices for a distributed server (re-execs)")
-    ap.add_argument("--ppn", type=int, default=4)
+                    help="distributed server on this many devices (virtual "
+                         "host devices under JAX_PLATFORMS=cpu; re-execs)")
+    ap.add_argument("--ppn", type=int, default=None,
+                    help="ranks per node of the (node, proc) mesh "
+                         "(default: devices // 2)")
     ap.add_argument("--pack", choices=["off", "width"], default="off",
                     help="width-packing policy (off = dispatch batching)")
     ap.add_argument("--max-pack-width", type=int, default=16,
@@ -95,14 +102,18 @@ def main():
     if args.dups >= args.requests:
         ap.error(f"--dups must be < --requests, got {args.dups} >= {args.requests}")
 
-    if args.devices and "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={args.devices}"
-        os.execv(sys.executable, [sys.executable, "-m", "repro.launch.serve"] + sys.argv[1:])
+    from repro.launch.runtime import (
+        default_tol, force_host_devices, setup_compilation_cache,
+        solver_dtype,
+    )
 
-    import jax
+    force_host_devices(args.devices, "repro.launch.serve")
+    dtype = solver_dtype()
+    setup_compilation_cache()
+    if args.tol is None:
+        args.tol = default_tol(dtype)
 
-    jax.config.update("jax_enable_x64", True)
-
+    from repro.launch.mesh import make_solver_mesh
     from repro.serve import ECGServer, ServeConfig, latency_percentiles
     from repro.solver import SolverConfig
 
@@ -113,11 +124,7 @@ def main():
         tracer = Tracer(sinks=[open_sink(args.trace)])
 
     t = "auto" if args.t == "auto" else int(args.t)
-    mesh = None
-    if args.devices:
-        mesh = jax.make_mesh(
-            (args.devices // args.ppn, args.ppn), ("node", "proc")
-        )
+    mesh = make_solver_mesh(args.devices, args.ppn) if args.devices else None
     server = ECGServer(
         ServeConfig(
             solver=SolverConfig(t=t, tol=args.tol, adaptive="rankrev"),
@@ -135,7 +142,7 @@ def main():
     )
 
     ops, trace = build_trace(args.requests, args.dups, args.scale,
-                             seed=args.seed)
+                             seed=args.seed, dtype=dtype)
     names = [name for name, _ in ops]
     print(f"# trace: {len(trace)} requests over {len(ops)} operators "
           f"({', '.join(f'{n}={a.shape[0]} rows' for n, a in ops)}), "

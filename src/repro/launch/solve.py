@@ -30,8 +30,6 @@ chosen t and every reduction event.
 from __future__ import annotations
 
 import argparse
-import os
-import sys
 import time
 
 
@@ -75,11 +73,17 @@ def main():
     ap.add_argument("--t", type=_parse_t, default=8,
                     help="enlarging factor, or 'auto' to pick it from the "
                          "iterations-vs-cost model")
-    ap.add_argument("--tol", type=float, default=1e-8)
+    ap.add_argument("--tol", type=float, default=None,
+                    help="residual tolerance (default: 1e-8 in float64, "
+                         "1e-5 in float32)")
     ap.add_argument("--strategy", default="tuned",
                     choices=["sequential", "standard", "2step", "3step", "optimal", "tuned"])
-    ap.add_argument("--devices", type=int, default=0, help="force host devices (re-execs)")
-    ap.add_argument("--ppn", type=int, default=4)
+    ap.add_argument("--devices", type=int, default=0,
+                    help="distributed solve on this many devices (virtual "
+                         "host devices under JAX_PLATFORMS=cpu; re-execs)")
+    ap.add_argument("--ppn", type=int, default=None,
+                    help="ranks per node of the (node, proc) mesh "
+                         "(default: devices // 2)")
     ap.add_argument("--backend", default="jnp", choices=["jnp", "pallas"])
     ap.add_argument("--overlap", action="store_true",
                     help="hide halo exchange behind interior SpMBV compute")
@@ -140,15 +144,19 @@ def main():
     if args.tune is None:
         args.tune = "model" if (args.strategy == "tuned" or args.t == "auto") else "off"
 
-    if args.devices and "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={args.devices}"
-        os.execv(sys.executable, [sys.executable, "-m", "repro.launch.solve"] + sys.argv[1:])
+    from repro.launch.runtime import (
+        default_tol, force_host_devices, setup_compilation_cache,
+        solver_dtype,
+    )
 
-    import jax
-
-    jax.config.update("jax_enable_x64", True)
+    force_host_devices(args.devices, "repro.launch.solve")
     import numpy as np
     import jax.numpy as jnp
+
+    dtype = solver_dtype()
+    setup_compilation_cache()
+    if args.tol is None:
+        args.tol = default_tol(dtype)
 
     tracer = None
     if args.trace:
@@ -168,21 +176,25 @@ def main():
     from repro.core.cg import _cg_solve
     from repro.core.machines import TPU_V5E_POD
     from repro.core.methods import get_method
+    from repro.launch.mesh import make_solver_mesh
+    from repro.serve.packing import true_relres
     from repro.solver import (
         AdaptiveConfig, CommConfig, ECGSolver, KernelConfig, MethodConfig,
         SolverConfig, TuneConfig,
     )
 
     a = {
-        "dg": lambda: dg_laplace_2d((args.elements, args.elements), block=args.block),
-        "fd": lambda: fd_laplace_2d(args.elements * 4),
-        "random": lambda: random_spd(1024, density=0.02),
+        "dg": lambda: dg_laplace_2d((args.elements, args.elements),
+                                    block=args.block, dtype=dtype),
+        "fd": lambda: fd_laplace_2d(args.elements * 4, dtype=dtype),
+        "random": lambda: random_spd(1024, density=0.02, dtype=dtype),
     }[args.matrix]()
     rng = np.random.default_rng(0)
-    b = rng.standard_normal(a.shape[0])
-    print(f"matrix: {a.shape[0]} rows, {a.nnz} nnz; t={args.t}")
+    b = rng.standard_normal(a.shape[0]).astype(dtype)
+    print(f"matrix: {a.shape[0]} rows, {a.nnz} nnz, {np.dtype(dtype).name}; t={args.t}")
 
     sequential = args.strategy == "sequential" or not args.devices
+    mesh = None if sequential else make_solver_mesh(args.devices, args.ppn)
     if sequential and args.tune == "measure":
         print("note: measured tuning needs a device mesh; using the model "
               "for the sequential run")
@@ -195,7 +207,8 @@ def main():
         comm=CommConfig(
             strategy=strategy,
             overlap=args.overlap,
-            machine=None if sequential else TPU_V5E_POD.with_ppn(args.ppn),
+            machine=(None if sequential
+                     else TPU_V5E_POD.with_ppn(mesh.shape["proc"])),
         ),
         kernel=KernelConfig(backend=args.backend, ell_block=args.ell_block),
         # None = solver defaults (auto-t turns on rankrev); explicit "off" sticks
@@ -224,8 +237,7 @@ def main():
         _close_trace()
         return
 
-    n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev // args.ppn, args.ppn), ("node", "proc"))
+    n_dev = mesh.size
     t0 = time.time()
     solver = ECGSolver.build(a, mesh, config, b=b)
     res = solver.solve(b)
@@ -237,9 +249,7 @@ def main():
         if "p2p" in cfg.predicted:
             print("  p2p model:",
                   {k: f"{v*1e6:.0f}us" for k, v in cfg.predicted["p2p"].items()})
-    x = solver.unshard(res.x)
-    relres = np.linalg.norm(np.asarray(a.todense(), np.float64) @ x - b) / np.linalg.norm(b) \
-        if a.shape[0] <= 8192 else float("nan")
+    relres = true_relres(a, solver.unshard(res.x), b)
     print(
         f"distributed ECG[{mtag}/{strategy}/{args.backend}"
         f"{'/overlap' if solver.op.overlap else ''}] t={res.t} on {n_dev} devices: "

@@ -176,7 +176,7 @@ def row_parallel_out(cfg: ArchConfig, mesh: Mesh, axes: MeshAxes, h, w, eq, cont
     )
     if not ok:
         return jnp.einsum(eq, h, w)
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def body(hh, ww):
@@ -188,7 +188,7 @@ def row_parallel_out(cfg: ArchConfig, mesh: Mesh, axes: MeshAxes, h, w, eq, cont
         mesh=mesh,
         in_specs=(P(axes.batch, None, axes.model), P(axes.model, None)),
         out_specs=P(axes.batch, axes.model, None),
-        check_rep=False,
+        check_vma=False,
     )
     return f(h, w)
 

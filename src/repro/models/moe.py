@@ -21,7 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.models.common import ArchConfig, MeshAxes
 
@@ -52,7 +52,7 @@ def moe_ffn(cfg: ArchConfig, mesh: Mesh, axes: MeshAxes, x, p):
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
     out, aux = f(x, p["router"], p["we_g"], p["we_u"], p["we_d"])
     return out, aux

@@ -99,7 +99,7 @@ def build_distributed_preconditioner(a, cfg: PreconditionConfig, op, mesh, a_app
 
     # block_jacobi: per-rank factors, shard_map'd local batched solves
     import jax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
@@ -128,6 +128,6 @@ def build_distributed_preconditioner(a, cfg: PreconditionConfig, op, mesh, a_app
         mesh=mesh,
         in_specs=(P(("node", "proc"), None, None), op.vec_spec),
         out_specs=op.vec_spec,
-        check_rep=False,
+        check_vma=False,
     )
     return lambda x, k: smapped(factors, x)

@@ -31,15 +31,21 @@ def fingerprint_csr(a) -> str:
     data = np.asarray(a.data)
     n_rows = len(indptr) - 1
     # within-row canonical column order (stable for the extremely unlikely
-    # duplicate-entry case: lexsort keys are (secondary, primary))
-    row_of = np.repeat(np.arange(n_rows, dtype=np.int64), np.diff(indptr))
-    order = np.lexsort((indices, row_of))
+    # duplicate-entry case: lexsort keys are (secondary, primary)); rows
+    # already in order — what assemblers usually emit — skip the sort
+    step = np.diff(indices)
+    starts = indptr[1:-1]
+    step[starts[(starts > 0) & (starts < len(indices))] - 1] = 0  # row changes
+    if not np.all(step >= 0):
+        row_of = np.repeat(np.arange(n_rows, dtype=np.int64), np.diff(indptr))
+        order = np.lexsort((indices, row_of))
+        indices, data = indices[order], data[order]
     h = hashlib.blake2b(digest_size=16)
     h.update(np.asarray(a.shape, dtype=np.int64).tobytes())
     h.update(data.dtype.str.encode())
     h.update(indptr.tobytes())
-    h.update(np.ascontiguousarray(indices[order]).tobytes())
-    h.update(np.ascontiguousarray(data[order]).tobytes())
+    h.update(np.ascontiguousarray(indices).tobytes())
+    h.update(np.ascontiguousarray(data).tobytes())
     return h.hexdigest()
 
 

@@ -88,20 +88,20 @@ class PackingConfig:
 def true_relres(a, x, b) -> float:
     """Host-side true relative residual ``‖A·x − b‖ / ‖b‖`` of a solution.
 
-    Computed from the raw CSR arrays with numpy (one bincount segment-sum)
+    Computed in float64 from the raw CSR arrays with numpy (one bincount
+    segment-sum)
     — independent of the solver's kernels and recurrences on purpose: this
     is the *measurement* side of the packed relres contract, so it must not
     share code with the machinery it audits.
     """
-    x = np.asarray(x)
-    b = np.asarray(b)
+    x = np.asarray(x, np.float64)
+    b = np.asarray(b, np.float64)
     indptr = np.asarray(a.indptr)
     indices = np.asarray(a.indices)
-    data = np.asarray(a.data)
+    data = np.asarray(a.data, np.float64)
     n = int(a.shape[0])
     rows = np.repeat(np.arange(n), np.diff(indptr))
-    ax = np.bincount(rows, weights=np.asarray(data * x[indices], np.float64),
-                     minlength=n)
+    ax = np.bincount(rows, weights=data * x[indices], minlength=n)
     nb = float(np.linalg.norm(b))
     return float(np.linalg.norm(ax - b) / (nb if nb > 0 else 1.0))
 

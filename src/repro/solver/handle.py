@@ -40,9 +40,25 @@ import jax.numpy as jnp
 from repro.adaptive.groups import GroupSpec
 from repro.adaptive.reduce import resolve_policy
 from repro.core.cg import SolveResult
-from repro.core.ecg import finalize_result, make_ecg_runner
+from repro.core.ecg import finalize_result, jit_solve, make_ecg_runner
 from repro.observe.tracer import coerce_tracer
 from repro.solver.config import SolverConfig
+
+
+def _auto_axes(mesh):
+    """``mesh`` with every axis of type ``Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, which type-check the
+    sharding of every op outside the shard_maps (the t×t factorization and
+    its triangular solves on row-sharded blocks are refused); the solver
+    leaves that code to the compiler's sharding propagation.
+    """
+    from jax.sharding import AxisType, Mesh
+
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 @dataclasses.dataclass
@@ -114,7 +130,7 @@ class ECGSolver:
         """
         self = cls.__new__(cls)
         self.a = a
-        self.mesh = mesh
+        self.mesh = None if mesh is None else _auto_axes(mesh)
         self.config = SolverConfig.coerce(config)
         self._tracer = coerce_tracer(tracer)
         self.stats = SolverStats()
@@ -351,7 +367,7 @@ class ECGSolver:
     def _build_reducers(self):
         """The fused shard_map reductions of §3.1 (one psum each) and the
         padded-layout T_{r,t} splitting — built once per operator."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.kernels.block_update.ops import ecg_tail
         from repro.kernels.fused_gram.ops import fused_gram
@@ -365,7 +381,7 @@ class ECGSolver:
             mesh=mesh,
             in_specs=(vspec, vspec),
             out_specs=P(None, None),
-            check_rep=False,
+            check_vma=False,
         )
         if backend == "pallas":
             self._gram2 = shard_map(
@@ -375,7 +391,7 @@ class ECGSolver:
                 mesh=mesh,
                 in_specs=(vspec,) * 4,
                 out_specs=P(None, None),
-                check_rep=False,
+                check_vma=False,
             )
             self._tail = shard_map(
                 lambda x, r, pp, ap, po, c, d, do: ecg_tail(
@@ -384,7 +400,7 @@ class ECGSolver:
                 mesh=mesh,
                 in_specs=(vspec,) * 5 + (P(None, None),) * 3,
                 out_specs=(vspec, vspec, vspec),
-                check_rep=False,
+                check_vma=False,
             )
         else:
             self._gram2 = shard_map(
@@ -395,7 +411,7 @@ class ECGSolver:
                 mesh=mesh,
                 in_specs=(vspec,) * 4,
                 out_specs=P(None, None),
-                check_rep=False,
+                check_vma=False,
             )
             self._tail = None
         self._sqnorm = shard_map(
@@ -403,7 +419,7 @@ class ECGSolver:
             mesh=mesh,
             in_specs=P(("node", "proc")),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
         # per-column squared norms for packed multi-RHS solves: one psum of
         # g floats that REPLACES the scalar sqnorm collective in group mode
@@ -413,7 +429,7 @@ class ECGSolver:
             mesh=mesh,
             in_specs=vspec,
             out_specs=P(None),
-            check_rep=False,
+            check_vma=False,
         )
         # preconditioned packed reduction [PᵀR | APᵀW | AP_oldᵀW]: three
         # asymmetric products the fused_gram kernel cannot express, fused
@@ -427,7 +443,7 @@ class ECGSolver:
             mesh=mesh,
             in_specs=(vspec,) * 5,
             out_specs=P(None, None),
-            check_rep=False,
+            check_vma=False,
         )
 
         # T_{r,t} on the padded layout: subdomains follow *true* global row
@@ -531,7 +547,7 @@ class ECGSolver:
                 def go(carry):
                     self.stats.traces += 1
                     return runner.run(carry)
-            fn = jax.jit(go)
+            fn = jit_solve(go)
             self._jits[key] = fn
         return fn
 
@@ -776,7 +792,7 @@ class ECGSolver:
                 def go(carry):
                     self.stats.traces += 1
                     return runner.run(carry)
-            fn = jax.jit(go)
+            fn = jit_solve(go)
             self._jits[key] = fn
         return fn
 
@@ -1053,15 +1069,24 @@ class ECGSolver:
     def lowered_text(self, dtype=None, width: int | None = None) -> str:
         """Compiled HLO of the (fresh) solve program at ``width`` — used by
         the collective-structure tests (§3.1 two-psum invariant)."""
-        import numpy as _np
-
-        dtype = jnp.float64 if dtype is None else dtype
+        dtype = self.a.data.dtype if dtype is None else dtype
         width = self.t if width is None else width
-        n = self.op.n_padded if self.op is not None else self.a.shape[0]
-        if self.mesh is not None:
-            self._onehot(dtype)  # warm eagerly — a trace must not put
-        sds = jax.ShapeDtypeStruct((n,), _np.dtype(dtype))
+        sds = self._vec_struct((), dtype)
         return self._jit(width, "fresh").lower(sds, sds).compile().as_text()
+
+    def _vec_struct(self, cols: tuple, dtype):
+        """Shape, dtype and placement of the solve's (n, *cols) operands,
+        as :meth:`solve` lays them out (so an AOT compile from it is the
+        program the solve call runs)."""
+        if self.mesh is None:
+            return jax.ShapeDtypeStruct((self.a.shape[0],) + cols, np.dtype(dtype))
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        self._onehot(dtype)  # warm eagerly — a trace must not put
+        return jax.ShapeDtypeStruct(
+            (self.op.n_padded,) + cols, np.dtype(dtype),
+            sharding=NamedSharding(self.mesh, P(("node", "proc"), *(None,) * len(cols))),
+        )
 
     def packed_lowered_text(
         self, tols, dtype=None, width_seg: int | None = None
@@ -1070,9 +1095,7 @@ class ECGSolver:
         layout of ``len(tols)`` requests, at exchange width ``width_seg`` —
         used by the retirement re-slice gates (all-reduce count unchanged,
         collective-permute payload drops with the live width)."""
-        import numpy as _np
-
-        dtype = jnp.float64 if dtype is None else dtype
+        dtype = self.a.data.dtype if dtype is None else dtype
         spec = GroupSpec(
             t_each=self.t,
             tols=tuple(
@@ -1080,9 +1103,6 @@ class ECGSolver:
             ),
         )
         width_seg = spec.width if width_seg is None else width_seg
-        n = self.op.n_padded if self.op is not None else self.a.shape[0]
-        if self.mesh is not None:
-            self._onehot(dtype)  # warm eagerly — a trace must not put
-        sds = jax.ShapeDtypeStruct((n, spec.n_groups), _np.dtype(dtype))
+        sds = self._vec_struct((spec.n_groups,), dtype)
         fn = self._packed_jit(spec, width_seg, "fresh")
         return fn.lower(sds, sds).compile().as_text()

@@ -40,20 +40,19 @@ def _kron_block_csr(
 
     new_indices = np.empty(nnz * b * b, dtype=np.int32)
     new_data = np.empty(nnz * b * b, dtype=block.dtype)
-    pos = 0
-    col_offsets = np.arange(b, dtype=np.int32)
-    for i in range(n):
-        s, e = indptr[i], indptr[i + 1]
-        cols = indices[s:e]
-        vals = data[s:e]
-        # block row layout: for each of the b sub-rows, all (col, b) entries
-        blk_cols = (cols[:, None] * b + col_offsets[None, :]).reshape(-1)  # (k*b,)
-        k = e - s
-        for r in range(b):
-            chunk = (vals[:, None] * block[r][None, :]).reshape(-1)
-            new_indices[pos : pos + k * b] = blk_cols
-            new_data[pos : pos + k * b] = chunk
-            pos += k * b
+    # block row layout: for each of the b sub-rows of scalar row i, all
+    # (col, b) entries of row i — nonzero p = j-th of row i, sub-row r and
+    # block column c land at new_indptr[i*b + r] + j*b + c
+    offs = np.arange(b)
+    row_of = np.repeat(np.arange(n), row_counts)
+    j = np.arange(nnz) - np.asarray(indptr)[row_of]
+    pos = (
+        new_indptr[(row_of * b)[:, None] + offs][:, :, None]
+        + (j * b)[:, None, None]
+        + offs[None, None, :]
+    )
+    new_indices[pos] = (np.asarray(indices, np.int64)[:, None] * b + offs)[:, None, :]
+    new_data[pos] = np.asarray(data)[:, None, None] * block[None, :, :]
     return CSRMatrix(
         indptr=jnp.asarray(new_indptr, jnp.int32),
         indices=jnp.asarray(new_indices),

@@ -70,7 +70,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.sparse.csr import CSRMatrix
 from repro.sparse.partition import (
@@ -80,6 +80,7 @@ from repro.sparse.partition import (
     rebased_local_csr,
 )
 from repro.core.node_aware import ExchangePlan, build_exchange_plan
+from repro.kernels.bsr_spmbv.kernel import LANES
 from repro.kernels.bsr_spmbv.ops import (
     bsr_spmbv,
     count_block_ell_tiles,
@@ -240,7 +241,7 @@ class DistributedSpMBV:
 
     def _ell_spmbv(self, xfull, blocks, indices):
         """Block-ELL SpMBV; pads xfull to the tile grid the blocks index."""
-        bc = blocks.shape[-1]
+        bc = blocks.shape[1] // indices.shape[-1]
         m_pad = (xfull.shape[0] + bc - 1) // bc * bc
         vp = jnp.pad(xfull, ((0, m_pad - xfull.shape[0]), (0, 0)))
         return bsr_spmbv(blocks, indices, vp)
@@ -332,7 +333,7 @@ class DistributedSpMBV:
             in_specs=(self.vec_spec, dev_specs, dev_specs, dev_specs)
             + (dev_specs,) * (2 * k),
             out_specs=self.vec_spec,
-            check_rep=False,
+            check_vma=False,
         )
 
         def apply(v):
@@ -420,7 +421,7 @@ def _stack_block_ell(per_rank, n_rows_max, n_cols, br, bc, dtype):
         [count_block_ell_tiles(g[1], g[2], len(g[0]), n_cols, br, bc) for g in per_rank]
         + [1]
     )
-    blocks = np.zeros((p, nbr, kmax, br, bc), dtype)
+    blocks = np.zeros((p, max(1, -(-nbr // LANES)), kmax * bc, br, LANES), dtype)
     idx = np.zeros((p, nbr, kmax), np.int32)
     for r, (rows, gptr, gix, gdat) in enumerate(per_rank):
         blocks[r], idx[r] = csr_arrays_to_block_ell(
@@ -595,7 +596,7 @@ def _make_distributed_spmbv(
             )
 
     dev_sharding = NamedSharding(mesh, P(("node", "proc")))
-    put = lambda arr: jax.device_put(jnp.asarray(arr), dev_sharding)
+    put = lambda arr: jax.device_put(arr, dev_sharding)
     return DistributedSpMBV(
         mesh=mesh,
         plan=plan,

@@ -82,7 +82,7 @@ def measure_dispatch_overhead(
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.analysis.ecg_bench import _timeit
@@ -105,7 +105,7 @@ def measure_dispatch_overhead(
             return x
         return jax.jit(shard_map(
             per_device, mesh=mesh, in_specs=P(), out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         ))
 
     rng = np.random.default_rng(0)

@@ -27,6 +27,7 @@ jax.config.update("jax_enable_x64", True)
 
 import numpy as np
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.sparse import dg_laplace_2d, fd_laplace_2d
 from repro.sparse.csr import csr_spmbv
@@ -232,12 +233,14 @@ def check_adaptive_opcode_count():
     lowers to exactly the same all-reduce count as the fixed-width body —
     the pivoted factorization and masking run on replicated t x t data and
     add NO collectives."""
-    mesh = jax.make_mesh((2, 4), ("node", "proc"))
+    # hand-built iteration bodies outside the solver: an Auto-axes mesh
+    # leaves their t x t algebra to sharding propagation, as the solver does
+    mesh = jax.make_mesh((2, 4), ("node", "proc"), axis_types=(AxisType.Auto,) * 2)
     a = dg_laplace_2d((4, 4), block=4)
     op = make_distributed_spmbv(a, mesh, "3step", t=4, machine=BLUE_WATERS)
     apply_a = op.matvec_fn()
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.core.ecg import _chol_inv_apply
     from repro.adaptive import rank_revealing_apply, stagnation_mask
     from repro.adaptive.reduce import ReductionPolicy
@@ -245,15 +248,15 @@ def check_adaptive_opcode_count():
     axes = ("node", "proc")
     vspec = op.vec_spec
     gram1 = shard_map(lambda z, az: jax.lax.psum(z.T @ az, axes), mesh=mesh,
-                      in_specs=(vspec, vspec), out_specs=P(None, None), check_rep=False)
+                      in_specs=(vspec, vspec), out_specs=P(None, None), check_vma=False)
     gram2 = shard_map(
         lambda pp, rr, ap, apo: jax.lax.psum(
             jnp.concatenate([pp.T @ rr, ap.T @ ap, apo.T @ ap], axis=1), axes
         ),
-        mesh=mesh, in_specs=(vspec,) * 4, out_specs=P(None, None), check_rep=False,
+        mesh=mesh, in_specs=(vspec,) * 4, out_specs=P(None, None), check_vma=False,
     )
     sqnorm = shard_map(lambda v: jax.lax.psum(jnp.vdot(v, v), axes), mesh=mesh,
-                       in_specs=P(axes), out_specs=P(), check_rep=False)
+                       in_specs=P(axes), out_specs=P(), check_vma=False)
     policy = ReductionPolicy()
 
     def body(z, r, p_old, ap_old, rn, adaptive):
@@ -318,14 +321,16 @@ def check_packed_exchange_lowering():
 def _permute_payload_elems(txt):
     """Total elements moved by collective-permutes in optimized HLO text —
     the p2p payload a packed solve pays per exchange sweep (sum over the
-    operand shapes of every collective-permute / collective-permute-start)."""
+    result shapes, equal to the operand shapes, of every
+    collective-permute / collective-permute-start; for the async start the
+    first element of its result tuple)."""
     import re
 
     total = 0
     for line in txt.splitlines():
-        m = re.search(
-            r" collective-permute(?:-start)?\([a-z0-9]+\[([\d,]+)\]", line
-        )
+        if not re.search(r" collective-permute(?:-start)?\(", line):
+            continue
+        m = re.search(r"= \(?[a-z]+[0-9]*\[([\d,]+)\]", line)
         if m:
             dims = [int(d) for d in m.group(1).split(",")]
             total += int(np.prod(dims))
@@ -704,11 +709,13 @@ def check_two_psums_per_iteration():
     (plus the convergence-norm reduction) — inspect the lowered HLO.  Count
     the ``all-reduce(`` opcode, not the bare substring: each instruction's
     SSA name (e.g. ``%all-reduce.1``) would otherwise double-count."""
-    mesh = jax.make_mesh((2, 4), ("node", "proc"))
+    # hand-built iteration bodies outside the solver: an Auto-axes mesh
+    # leaves their t x t algebra to sharding propagation, as the solver does
+    mesh = jax.make_mesh((2, 4), ("node", "proc"), axis_types=(AxisType.Auto,) * 2)
     a = dg_laplace_2d((4, 4), block=4)
     op = make_distributed_spmbv(a, mesh, "3step", t=4, machine=BLUE_WATERS)
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.kernels import fused_gram
 
     def n_allreduce(txt):
@@ -718,7 +725,7 @@ def check_two_psums_per_iteration():
     sds = jax.ShapeDtypeStruct((op.n_padded, 4), jnp.float64)
     gram1 = shard_map(
         lambda z, az: jax.lax.psum(z.T @ az, ("node", "proc")),
-        mesh=mesh, in_specs=(vspec, vspec), out_specs=P(None, None), check_rep=False,
+        mesh=mesh, in_specs=(vspec, vspec), out_specs=P(None, None), check_vma=False,
     )
     txt = jax.jit(gram1).lower(sds, sds).compile().as_text()
     assert n_allreduce(txt) == 1, (
@@ -730,7 +737,7 @@ def check_two_psums_per_iteration():
         lambda pp, rr, ap, apo: jax.lax.psum(
             fused_gram(pp, rr, ap, apo), ("node", "proc")
         ),
-        mesh=mesh, in_specs=(vspec,) * 4, out_specs=P(None, None), check_rep=False,
+        mesh=mesh, in_specs=(vspec,) * 4, out_specs=P(None, None), check_vma=False,
     )
     txt2 = jax.jit(gram2).lower(sds, sds, sds, sds).compile().as_text()
     assert n_allreduce(txt2) == 1, (

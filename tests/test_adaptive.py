@@ -307,12 +307,13 @@ class TestKernelDispatch:
 
     def test_gpu_fallback_silent_by_default(self, monkeypatch):
         from repro.kernels import dispatch
+        from repro.kernels.bsr_spmbv.kernel import LANES
         from repro.kernels.bsr_spmbv.ops import bsr_spmbv
 
         monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
         dispatch.reset_dispatch_warnings()
         monkeypatch.delenv("REPRO_KERNEL_VERBOSE", raising=False)
-        blocks = jnp.ones((1, 1, 4, 4))
+        blocks = jnp.ones((1, 4, 4, LANES))  # one 4x4 tile, lane-major
         idx = jnp.zeros((1, 1), jnp.int32)
         import warnings as _w
 

@@ -4,6 +4,7 @@ exercised only via the dry-run (ShapeDtypeStruct, no allocation)."""
 
 import numpy as np
 import jax
+from jax.sharding import AxisType
 import jax.numpy as jnp
 import pytest
 
@@ -15,7 +16,7 @@ from repro.train import build_train_step, AdamWConfig, init_opt_state, DataConfi
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 
 PUBLISHED_SIZES = {

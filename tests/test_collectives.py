@@ -34,13 +34,13 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, numpy as np, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.collectives.hierarchical import hierarchical_allreduce, tiered_collective_bytes
 
 mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
 x = jnp.arange(32.0).reshape(8, 4)
 flat = shard_map(lambda v: jax.lax.psum(v, ("pod", "data")), mesh=mesh,
-                 in_specs=P(), out_specs=P(), check_rep=False)
+                 in_specs=P(), out_specs=P(), check_vma=False)
 want = flat(x)
 got = hierarchical_allreduce(x, mesh)
 np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)

@@ -5,6 +5,7 @@ import os
 import signal
 import numpy as np
 import jax
+from jax.sharding import AxisType
 import jax.numpy as jnp
 import pytest
 
@@ -58,7 +59,7 @@ class TestOptimizer:
         assert float(lr_at(cfg, 110)) == pytest.approx(0.1, rel=1e-3)
 
     def test_zero1_spreads_over_data(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         from repro.models.common import MeshAxes
         axes = MeshAxes.from_mesh(mesh)
         specs = {"w": P(None, "model")}
@@ -114,7 +115,7 @@ class TestCheckpoint:
 
     def test_restore_onto_different_mesh_shape(self, tmp_path):
         """Elasticity: save under one sharding, restore under another."""
-        mesh_a = jax.make_mesh((1, 1), ("data", "model"))
+        mesh_a = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         tree = {"w": jax.device_put(jnp.arange(16.0).reshape(4, 4),
                                     jax.NamedSharding(mesh_a, P(None, None)))}
         save_checkpoint(tmp_path, 3, tree)
@@ -126,7 +127,7 @@ class TestCheckpoint:
 
     def test_resume_training_exact(self, tmp_path):
         """Train 4 steps straight == train 2, checkpoint, restore, train 2."""
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         api = model_api(TINY)
         bundle = build_train_step(TINY, mesh, AdamWConfig(lr=1e-3), batch=2, seq=16, donate=False)
         dcfg = DataConfig(vocab=TINY.vocab, batch=2, seq=16)
@@ -163,7 +164,7 @@ class TestCheckpoint:
 
 class TestMicrobatching:
     def test_accumulation_matches_full_batch(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         api = model_api(TINY)
         params = api.init_params(TINY, jax.random.key(1))
         dcfg = DataConfig(vocab=TINY.vocab, batch=4, seq=16)
