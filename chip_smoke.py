@@ -3,7 +3,6 @@
 
     python chip_smoke.py              # one chip: solve, solve_many, ECGServer
     python chip_smoke.py --chips 4    # the 2x2 ("node", "proc") path only
-    python chip_smoke.py --elements 80 64   # a smaller DG element grid
 
 The operator is the paper's Example 2.1 DG Laplacian,
 ``dg_laplace_2d((320, 256), block=16)`` in float32: 1,310,720 rows and
@@ -44,16 +43,17 @@ import numpy as np
 ELEMENTS = (320, 256)  # Example 2.1 element grid
 BLOCK = 16             # DG element block = Block-ELL tile
 T = 8
-# recursive residual norm (unit-norm right-hand sides).  Float32 ECG on this
-# operator reaches 1e-4 in about 1,200 iterations and then crawls (6.3e-5
-# after 3,000 on a v5e); 1e-5 is out of its reach at this size.
-TOL = 1e-4
-# true relative residual, float64 on the host: the float32 recurrence
+# true relative residual, float64 on the host, against the solver's float32
+# tolerance (``repro.launch.runtime.default_tol``): the float32 recurrence
 # drifts from it by about 1e-4 at this size (true 1.5e-4 at recursive
-# 6.3e-5 on a v5e), so the bound is 5 x TOL
-RELRES_BOUND = 5e-4
+# 6.3e-5 on a v5e), so the bound is 5x the tolerance
+RELRES_FACTOR = 5
 MAX_ITERS = 4000       # a cap: about three times what the solve needs
-ITER_SLACK = 5         # distributed vs one-device iteration counts
+# distributed vs one-device iteration counts: the 2x2 solve sums its Gram
+# products and tiles in another order, and in float32 that moves where the
+# residual crosses the tolerance by up to about 1% of the iterations at full
+# size (1,128 vs 1,136 on a v5e 2x2; identical at 81,920 rows)
+ITER_SLACK_FRACTION = 0.01
 SEED = 0
 
 
@@ -102,11 +102,17 @@ def peak_bytes(device) -> int | None:
     return stats.get("peak_bytes_in_use")
 
 
+def tolerance() -> float:
+    from repro.launch.runtime import default_tol
+
+    return default_tol(np.float32)
+
+
 def solver_config(strategy: str = "standard"):
     from repro.solver import CommConfig, KernelConfig, SolverConfig
 
     return SolverConfig(
-        t=T, tol=TOL, max_iters=MAX_ITERS,
+        t=T, tol=tolerance(), max_iters=MAX_ITERS,
         comm=CommConfig(strategy=strategy),
         kernel=KernelConfig(backend="pallas", ell_block=(BLOCK, BLOCK)),
     )
@@ -123,18 +129,19 @@ def relres_ok(a, x, b, label: str, res) -> float:
     log(f"{label}: recursive residual " + " ".join(
         f"{k}:{hist[k]:.2e}" for k in range(0, res.n_iters + 1, step)))
     check(bool(res.converged), f"{label} did not converge")
-    check(rr <= RELRES_BOUND, f"{label} true relres {rr} > {RELRES_BOUND}")
+    bound = RELRES_FACTOR * tolerance()
+    check(rr <= bound, f"{label} true relres {rr} > {bound}")
     return rr
 
 
-def build_operator(elements):
+def build_operator():
     import jax.numpy as jnp
 
     from repro.sparse import dg_laplace_2d
 
     t0 = time.perf_counter()
-    a = dg_laplace_2d(elements, block=BLOCK, dtype=jnp.float32)
-    log(f"operator: dg_laplace_2d{elements} block={BLOCK} float32: "
+    a = dg_laplace_2d(ELEMENTS, block=BLOCK, dtype=jnp.float32)
+    log(f"operator: dg_laplace_2d{ELEMENTS} block={BLOCK} float32: "
         f"rows={a.shape[0]} nnz={a.nnz}")
     log(f"setup: matrix generation {time.perf_counter() - t0:.3f}s")
     return a
@@ -171,10 +178,10 @@ def timed_solve(solver, b, label: str):
     return res, x
 
 
-def one_chip(devices, elements) -> None:
+def one_chip(devices) -> None:
     from repro.serve import ECGServer, ServeConfig
 
-    a = build_operator(elements)
+    a = build_operator()
     bs = rhs(a.shape[0], 6, np.float32)
 
     solver = build_solver(a)
@@ -215,16 +222,25 @@ def one_chip(devices, elements) -> None:
     log(f"peak_bytes_in_use: {peak_bytes(devices[0])}")
 
 
-def four_chips(devices, elements) -> None:
+def four_chips(devices) -> None:
     from repro.launch.mesh import make_solver_mesh
 
-    a = build_operator(elements)
+    a = build_operator()
     (b,) = rhs(a.shape[0], 1, np.float32)
+
+    # every solve runs and reports before a failed check ends the run
+    failures = []
+
+    def checked(fn, *args):
+        try:
+            fn(*args)
+        except SmokeFailure as e:
+            failures.append(str(e))
 
     ref = build_solver(a)
     compile_solve(ref, "1-device")
     res, x = timed_solve(ref, b, "1-device reference")
-    relres_ok(a, x, b, "1-device reference", res)
+    checked(relres_ok, a, x, b, "1-device reference", res)
     ref_iters = res.n_iters
     del ref, res
 
@@ -233,22 +249,21 @@ def four_chips(devices, elements) -> None:
         solver = build_solver(a, mesh, strategy)
         compile_solve(solver, f"2x2 {strategy}")
         res, x = timed_solve(solver, b, f"2x2 {strategy}")
-        relres_ok(a, x, b, f"2x2 {strategy}", res)
-        check(abs(res.n_iters - ref_iters) <= ITER_SLACK,
-              f"2x2 {strategy}: {res.n_iters} iterations vs {ref_iters} on "
-              "one device")
+        checked(relres_ok, a, x, b, f"2x2 {strategy}", res)
+        slack = max(5, ITER_SLACK_FRACTION * ref_iters)
+        checked(check, abs(res.n_iters - ref_iters) <= slack,
+                f"2x2 {strategy}: {res.n_iters} iterations vs {ref_iters} on "
+                "one device")
         del solver, res
     for d in devices[:4]:
         log(f"peak_bytes_in_use[{d.id}]: {peak_bytes(d)}")
+    check(not failures, "; ".join(failures))
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4 = run only the 2x2 distributed path")
-    ap.add_argument("--elements", type=int, nargs=2, default=ELEMENTS,
-                    metavar=("EX", "EY"),
-                    help="DG element grid (default: the paper's 320 256)")
     args = ap.parse_args(argv)
 
     sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
@@ -262,7 +277,7 @@ def main(argv=None) -> int:
         devices = require_tpu(args.chips)
         log(f"compilation cache: {setup_compilation_cache()}")
         run = one_chip if args.chips == 1 else four_chips
-        run(devices, tuple(args.elements))
+        run(devices)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

@@ -32,8 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-#: block rows per lane group of the operator layout (the TPU lane width)
-LANES = 128
+# block rows per lane group of the operator layout (the TPU lane width)
+from repro.kernels.lanes import LANES
 
 
 def _acc_dtype(dtype):

@@ -74,7 +74,7 @@ def main():
                     help="enlarging factor of the solver template, or 'auto'")
     ap.add_argument("--tol", type=float, default=None,
                     help="residual tolerance (default: 1e-8 in float64, "
-                         "1e-5 in float32)")
+                         "1e-4 in float32)")
     ap.add_argument("--max-batch", type=int, default=8)
     ap.add_argument("--max-pending", type=int, default=256)
     ap.add_argument("--cache-dir", default=None,

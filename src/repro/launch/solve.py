@@ -75,7 +75,7 @@ def main():
                          "iterations-vs-cost model")
     ap.add_argument("--tol", type=float, default=None,
                     help="residual tolerance (default: 1e-8 in float64, "
-                         "1e-5 in float32)")
+                         "1e-4 in float32)")
     ap.add_argument("--strategy", default="tuned",
                     choices=["sequential", "standard", "2step", "3step", "optimal", "tuned"])
     ap.add_argument("--devices", type=int, default=0,
