@@ -23,6 +23,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.observe import scopes
+
 
 def default_rank_rtol(dtype) -> float:
     """Relative pivot threshold: diagonal entries below ``rtol · max(diag G)``
@@ -86,16 +88,17 @@ def rank_revealing_apply(g: jax.Array, *mats: jax.Array, rtol: float | None = No
     and no cross-iteration column identification is assumed anywhere).
     """
     t = g.shape[0]
-    l, perm, rank = pivoted_cholesky(g, rtol=rtol)
-    active = jnp.arange(t) < rank
-    # unit-ize the dead columns so the triangular solve is nonsingular; their
-    # solution rows are garbage and are masked out below.
-    l_solve = l + jnp.diag(jnp.where(active, 0.0, 1.0).astype(l.dtype))
-    colmask = active.astype(l.dtype)[None, :]
-    outs = []
-    for m in mats:
-        mp = m[:, perm]
-        # solve Y·Lᵀ = M_p row-wise  =>  L·Yᵀ = M_pᵀ (lower-triangular solve)
-        y = jax.scipy.linalg.solve_triangular(l_solve, mp.T, lower=True).T
-        outs.append(y * colmask)
+    with jax.named_scope(scopes.FACTOR):
+        l, perm, rank = pivoted_cholesky(g, rtol=rtol)
+        active = jnp.arange(t) < rank
+        # unit-ize the dead columns so the triangular solve is nonsingular;
+        # their solution rows are garbage and are masked out below.
+        l_solve = l + jnp.diag(jnp.where(active, 0.0, 1.0).astype(l.dtype))
+        colmask = active.astype(l.dtype)[None, :]
+        outs = []
+        for m in mats:
+            mp = m[:, perm]
+            # solve Y·Lᵀ = M_p row-wise  =>  L·Yᵀ = M_pᵀ (lower-triangular solve)
+            y = jax.scipy.linalg.solve_triangular(l_solve, mp.T, lower=True).T
+            outs.append(y * colmask)
     return outs, rank, active

@@ -28,7 +28,7 @@ def _timeit(fn, *args, repeats: int = 3) -> float:
 
     Delegates to the shared :func:`repro.observe.timed_median_us` timer —
     the measurement discipline is identical across every benchmark, and an
-    installed ambient tracer sees each timed call as a ``bench/*`` span.
+    installed ambient tracer sees each timed call as a ``timed/*`` span.
     """
     from repro.observe import get_tracer, timed_median_us
 

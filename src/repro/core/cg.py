@@ -18,6 +18,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.observe import scopes
+
 #: ``SolveResult.event_hist`` bitmask values.
 EV_RECOVERY = 1  # rank-revealing factorization dropped live directions
 EV_RESEED = 2    # flexible restart reseeded Z from the preconditioned residual
@@ -186,15 +188,17 @@ def _guarded_while(cond_extra, body_fn, init: dict):
     """
 
     def cond(carry):
-        return (~carry["bd"]) & cond_extra(carry)
+        with jax.named_scope(scopes.CHECK):
+            return (~carry["bd"]) & cond_extra(carry)
 
     def body(carry):
         new = body_fn(carry)
-        ok = jnp.isfinite(new["rn"])
-        merged = jax.tree_util.tree_map(
-            lambda old, cur: jnp.where(ok, cur, old), carry, new
-        )
-        merged["bd"] = carry["bd"] | ~ok
+        with jax.named_scope(scopes.CHECK):
+            ok = jnp.isfinite(new["rn"])
+            merged = jax.tree_util.tree_map(
+                lambda old, cur: jnp.where(ok, cur, old), carry, new
+            )
+            merged["bd"] = carry["bd"] | ~ok
         return merged
 
     init = dict(init, bd=~jnp.isfinite(init["rn"]))

@@ -74,6 +74,7 @@ from repro.core.methods import MethodContext, get_method
 from repro.core.methods.base import _apply_vec, _chol_inv_apply  # noqa: F401  (back-compat re-exports)
 from repro.kernels.block_update.ops import ecg_tail
 from repro.kernels.fused_gram.ops import fused_gram
+from repro.observe.scopes import CHECK, GRAM, SPMBV, UPDATE, scoped
 
 
 class _ConstArgJit:
@@ -292,13 +293,17 @@ def make_ecg_runner(
             sqnorm_cols = lambda m: jnp.sum(m * m, axis=0)
     use_mask = a_apply_masked is not None and policy is not None
 
+    # every scheme builds its iteration from these closures, so each stage
+    # is named once here (repro.observe.scopes)
     ctx = MethodContext(
         t=t, s=s, max_iters=max_iters, policy=policy, use_mask=use_mask,
         chol_eps=chol_eps, reorth=reorth, rank_rtol=rank_rtol,
-        backend=backend, a_apply=a_apply, a_apply_masked=a_apply_masked,
-        split_fn=split_fn, gram1=gram1, gram2=gram2, sqnorm=sqnorm, tail=tail,
-        precond=precond, gram2p=gram2p, precond_reseed=precond_reseed,
-        groups=groups, sqnorm_cols=sqnorm_cols,
+        backend=backend, a_apply=scoped(SPMBV, a_apply),
+        a_apply_masked=scoped(SPMBV, a_apply_masked), split_fn=split_fn,
+        gram1=scoped(GRAM, gram1), gram2=scoped(GRAM, gram2),
+        sqnorm=scoped(CHECK, sqnorm), tail=scoped(UPDATE, tail), precond=precond,
+        gram2p=scoped(GRAM, gram2p), precond_reseed=precond_reseed,
+        groups=groups, sqnorm_cols=scoped(CHECK, sqnorm_cols),
     )
     spec.validate(ctx)
     init, iterate = spec.build(ctx)

@@ -34,18 +34,21 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.observe import scopes
+
 
 def _chol_inv_apply(g: jax.Array, *mats: jax.Array, eps: float = 0.0):
     """Given G = CᵀC, return [M C⁻¹ for M in mats] via triangular solves."""
     t = g.shape[0]
-    if eps:
-        g = g + eps * jnp.eye(t, dtype=g.dtype)
-    c = jnp.linalg.cholesky(g, upper=True)  # G = CᵀC with C upper-triangular
-    outs = []
-    for m in mats:
-        # solve Y C = M  =>  Cᵀ Yᵀ = Mᵀ  (lower-triangular solve)
-        y = jax.scipy.linalg.solve_triangular(c.T, m.T, lower=True).T
-        outs.append(y)
+    with jax.named_scope(scopes.FACTOR):
+        if eps:
+            g = g + eps * jnp.eye(t, dtype=g.dtype)
+        c = jnp.linalg.cholesky(g, upper=True)  # G = CᵀC with C upper-triangular
+        outs = []
+        for m in mats:
+            # solve Y C = M  =>  Cᵀ Yᵀ = Mᵀ  (lower-triangular solve)
+            y = jax.scipy.linalg.solve_triangular(c.T, m.T, lower=True).T
+            outs.append(y)
     return outs
 
 

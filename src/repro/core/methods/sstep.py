@@ -51,12 +51,14 @@ per s effective iterations.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.adaptive.rankrev import rank_revealing_apply
 from repro.adaptive.reduce import plateau_update, stagnation_mask
 from repro.core.cg import EV_RECOVERY
 from repro.core.methods.base import MethodContext, MethodSpec, _apply_vec
+from repro.observe import scopes
 
 
 class SStepMethod(MethodSpec):
@@ -165,8 +167,10 @@ class SStepMethod(MethodSpec):
 
             c = gram1(p, big_r)  # psum #2: (st, t) coefficient block = PᵀR
             # exact A-norm error projection onto span(P): monotone per block
-            big_x = big_x + p @ c
-            big_r = big_r - ap @ c
+            # (inline here: s-step has no tail closure to carry the scope)
+            with jax.named_scope(scopes.UPDATE):
+                big_x = big_x + p @ c
+                big_r = big_r - ap @ c
 
             rsum = big_r.sum(axis=1)
             rn = jnp.sqrt(sqnorm(rsum))

@@ -79,4 +79,5 @@ def block_trisolve_pallas(l, x, *, interpret: bool = False):
         out_specs=pl.BlockSpec((1, bs, t), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, bs, t), x.dtype),
         interpret=interpret,
+        name="block_trisolve",
     )(l, x)

@@ -35,7 +35,7 @@ def _mm(a, b):
     return jnp.dot(a, b, precision=_HIGHEST, preferred_element_type=acc)
 
 
-def _lanes_call(kernel, vecs, coefs, n_out, block_rows, interpret):
+def _lanes_call(kernel, name, vecs, coefs, n_out, block_rows, interpret):
     """Run ``kernel`` over lane-dense views of the (n, t) ``vecs`` with the
     (t, t) ``coefs`` as block diagonals; returns ``n_out`` (n, t) arrays."""
     n, t = vecs[0].shape
@@ -53,6 +53,7 @@ def _lanes_call(kernel, vecs, coefs, n_out, block_rows, interpret):
         out_specs=[spec] * n_out,
         out_shape=[jax.ShapeDtypeStruct((m, lanes), x.dtype) for x in vecs[:n_out]],
         interpret=interpret,
+        name=name,
     )(*ops, *(block_diag(c, tp, fold) for c in coefs))
     return tuple(from_lanes(o, n, t, tp) for o in outs)
 
@@ -65,7 +66,7 @@ def _kernel(x_ref, r_ref, p_ref, ap_ref, c_ref, xo_ref, ro_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def block_update_pallas(x, r, p, ap, c, *, block_rows: int = 512, interpret: bool = False):
-    return _lanes_call(_kernel, (x, r, p, ap), (c,), 2, block_rows, interpret)
+    return _lanes_call(_kernel, "block_update", (x, r, p, ap), (c,), 2, block_rows, interpret)
 
 
 def _tail_kernel(x_ref, r_ref, p_ref, ap_ref, po_ref, c_ref, d_ref, do_ref,
@@ -84,5 +85,6 @@ def ecg_tail_pallas(x, r, p, ap, p_old, c, d, d_old, *, block_rows: int = 512,
     """Fused ECG tail: (X+P·c, R−AP·c, AP−P·d−P_old·d_old) in one row pass."""
     # outputs take the dtypes of x, r and p (= ap's in the solver)
     return _lanes_call(
-        _tail_kernel, (x, r, p, ap, p_old), (c, d, d_old), 3, block_rows, interpret
+        _tail_kernel, "ecg_tail", (x, r, p, ap, p_old), (c, d, d_old), 3, block_rows,
+        interpret,
     )

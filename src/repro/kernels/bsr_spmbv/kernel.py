@@ -93,6 +93,7 @@ def bsr_spmbv_pallas(blocks, indices, v, *, interpret: bool = False):
         out_specs=pl.BlockSpec((1, br, t, lanes), lambda g: (g, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nbg, br, t, lanes), v.dtype),
         interpret=interpret,
+        name="bsr_spmbv",
     )(blocks, vg)
     # (g, i, j, l) -> row (g·L + l)·br + i, column j
     return out.transpose(0, 3, 1, 2).reshape(nbg * lanes * br, t)[: nbr * br]

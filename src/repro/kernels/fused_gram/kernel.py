@@ -59,6 +59,7 @@ def fused_gram_pallas(p, r, ap, ap_old, *, block_rows: int = 512, interpret: boo
         out_specs=pl.BlockSpec((lanes, 3 * lanes), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((lanes, 3 * lanes), acc),
         interpret=interpret,
+        name="fused_gram",
     )(*ops)
     parts = [diag_sum(g[:, k * lanes : (k + 1) * lanes], t, tp, fold) for k in range(3)]
     return jnp.concatenate(parts, axis=1).astype(p.dtype)

@@ -53,6 +53,7 @@ def halo_pack_pallas(src, idx, *, interpret: bool = False):
         ),
         out_shape=jax.ShapeDtypeStruct((c, 1, w), src.dtype),
         interpret=interpret,
+        name="halo_pack",
     )(idx, src.reshape(m, 1, w)).reshape(c, w)
 
 
@@ -84,4 +85,5 @@ def halo_unpack_pallas(dst, buf, pos, *, interpret: bool = False):
         out_shape=jax.ShapeDtypeStruct((m, 1, w), dst.dtype),
         input_output_aliases={1: 0},  # dst (first post-prefetch operand) -> out
         interpret=interpret,
+        name="halo_unpack",
     )(pos, dst.reshape(m, 1, w), buf.reshape(c, 1, w)).reshape(m, w)

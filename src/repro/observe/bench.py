@@ -38,7 +38,7 @@ def timed_median(fn, *args, repeats: int = 3, warmup: int = 1,
     calls of ``fn(*args, **kw)``.
 
     warmup: untimed leading calls (compile/caches; 0 to time cold).
-    tracer: each timed call becomes one ``bench/<label>`` span on it; a
+    tracer: each timed call becomes one ``timed/<label>`` span on it; a
             None or disabled tracer falls back to a sink-less measuring
             tracer (pure timing, zero records).
     sync:   ``jax.block_until_ready`` the result inside the timed region
@@ -54,7 +54,7 @@ def timed_median(fn, *args, repeats: int = 3, warmup: int = 1,
             _sync(out)
     ts = []
     for i in range(repeats):
-        with tr.span(f"bench/{label}", cat="bench", rep=i) as sp:
+        with tr.span(f"timed/{label}", cat="bench", rep=i) as sp:
             out = fn(*args, **kw)
             if sync:
                 _sync(out)

@@ -22,6 +22,13 @@ Two invariants keep the tracer honest:
   clock is read, no object allocated per call, and instrumented code
   never branches on a flag.
 
+An enabled tracer's ``with tracer.span(...)`` also opens a
+``jax.profiler.TraceAnnotation`` of the same name for the span's extent,
+so in any ``jax.profiler`` profile the program's spans lie on the device
+trace's own clock.  Spans recorded with explicit timestamps
+(:meth:`Tracer.emit`) and ``begin``/``end`` pairs stay on the host clock
+only.
+
 Usage::
 
     from repro.observe import Tracer, ChromeTraceSink
@@ -42,6 +49,8 @@ from __future__ import annotations
 
 import dataclasses
 import time
+
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -68,18 +77,22 @@ class Span:
 
 class _SpanCtx:
     """Context manager that closes ``span`` on exit — including via an
-    exception, so a failing build still produces a well-formed trace."""
+    exception, so a failing build still produces a well-formed trace —
+    and mirrors it into the profiler as a ``TraceAnnotation``."""
 
-    __slots__ = ("_tracer", "span")
+    __slots__ = ("_tracer", "span", "_mirror")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self._tracer = tracer
         self.span = span
+        self._mirror = TraceAnnotation(span.name)
 
     def __enter__(self) -> Span:
+        self._mirror.__enter__()
         return self.span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        self._mirror.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.span.args.setdefault("error", exc_type.__name__)
         self._tracer.end(self.span)

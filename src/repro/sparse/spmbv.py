@@ -87,6 +87,7 @@ from repro.kernels.bsr_spmbv.ops import (
     csr_arrays_to_block_ell,
 )
 from repro.kernels.halo_pack.ops import halo_pack, halo_unpack
+from repro.observe import scopes
 
 
 @dataclasses.dataclass
@@ -188,6 +189,10 @@ class DistributedSpMBV:
         reshapes ``(rmax, t) -> (rmax·cs, t/cs)`` around the rounds (padding
         t up to a multiple of cs when the applied width differs from the
         width the plan was sliced for, e.g. the width-1 initial residual)."""
+        with jax.named_scope(scopes.EXCHANGE):
+            return self._exchange_rounds(x_local, plan, gathers, scatters)
+
+    def _exchange_rounds(self, x_local, plan: ExchangePlan, gathers, scatters):
         t = x_local.shape[-1]
         cs = plan.col_split
         if cs > 1:

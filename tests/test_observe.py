@@ -11,6 +11,11 @@ hot loop.
 import dataclasses
 import json
 import os
+import re
+import subprocess
+import sys
+import time
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -315,7 +320,7 @@ class TestTimedMedian:
         tr = Tracer(sinks=[sink])
         timed_median(lambda: None, repeats=3, warmup=0, label="unit",
                      tracer=tr, sync=False)
-        spans = sink.by_name("bench/unit")
+        spans = sink.by_name("timed/unit")
         assert len(spans) == 3
         assert [s.args["rep"] for s in spans] == [0, 1, 2]
 
@@ -380,6 +385,40 @@ class TestSolverTracing:
         txt0 = plain._jit(plain.t, "fresh").lower(b_dev, x0).as_text()
         txt1 = traced._jit(traced.t, "fresh").lower(b_dev, x0).as_text()
         assert txt0 == txt1
+
+    def test_spans_mirror_into_the_profiler(self, seq_problem, tmp_path):
+        """An enabled tracer's spans are profiler annotations on the device
+        trace's clock, where a ``bench/clock`` anchor would have moved them;
+        the null tracer opens none."""
+        from chipbench import trace as tr
+
+        a, b = seq_problem
+        cfg = SolverConfig(t=4, tol=1e-8)
+        sink = MemorySink()
+        traced = ECGSolver.build(a, config=cfg, tracer=Tracer(sinks=[sink]))
+        plain = ECGSolver.build(a, config=cfg)
+        traced.solve(b), plain.solve(b)  # compile before the profile
+        n0 = len(sink.spans)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with jax.profiler.TraceAnnotation("bench/clock"):
+                clock_perf = time.perf_counter()
+            traced.solve(b)
+            plain.solve(b)
+        finally:
+            jax.profiler.stop_trace()
+        path = next(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+        clock = tr.load(path).annotation("bench/clock")
+        got = tr.load(path, annotation_prefix="solve/").annotations
+        want = sorted((s for s in sink.spans[n0:]
+                       if s.name in ("solve/dispatch", "solve/finalize")),
+                      key=lambda s: s.t0)
+        assert [g.name for g in got] == [s.name for s in want]
+        assert [s.name for s in want] == ["solve/dispatch", "solve/finalize"]
+        for g, s in zip(got, want):
+            start = clock.start + (s.t0 - clock_perf) * 1e9
+            assert abs(g.start - start) < 0.5e6
+            assert abs(g.end - (start + s.dur * 1e9)) < 0.5e6
 
     def test_with_config_clone_shares_tracer(self, seq_problem):
         a, _ = seq_problem
@@ -471,3 +510,103 @@ class TestDriftHelpers:
             predicted_iteration_seconds(solver)
         with pytest.raises(ValueError, match="distributed"):
             bytes_drift(solver)
+
+
+# ------------------------------------------------------------ stage scopes
+#: lowers the solve program of each iteration scheme on ``argv[1]`` CPU
+#: devices, with the Pallas kernels' bodies interpreted in place of the
+#: jnp oracles, and prints each compiled HLO text
+_LOWER_SCHEMES = r"""
+import importlib, json, sys
+import jax.numpy as jnp
+for name in ("bsr_spmbv", "fused_gram", "block_update", "halo_pack"):
+    importlib.import_module(f"repro.kernels.{name}.ops").resolve_dispatch = (
+        lambda op, use: (True, True))
+from repro.launch.mesh import make_solver_mesh
+from repro.solver import ECGSolver, KernelConfig, SolverConfig
+from repro.solver.config import MethodConfig
+from repro.sparse.matrices import dg_laplace_2d
+
+devices = int(sys.argv[1])
+a = dg_laplace_2d((4, 4), block=16, dtype=jnp.float32)
+mesh = make_solver_mesh(devices) if devices > 1 else None
+out = {}
+for method in ("classic", "pipelined", "sstep"):
+    cfg = SolverConfig(t=4, tol=1e-6, max_iters=50, tune="off",
+                       kernel=KernelConfig(backend="pallas", ell_block=(16, 16)),
+                       method=MethodConfig(name=method, s=2 if method == "sstep" else 1))
+    out[method] = ECGSolver.build(a, mesh, cfg).lowered_text()
+print(json.dumps(out))
+"""
+
+#: the stage scope each kernel (by its ``pallas_call`` name) runs under
+KERNEL_STAGE = {"bsr_spmbv": "ecg.spmbv", "fused_gram": "ecg.gram",
+                "ecg_tail": "ecg.update", "halo_pack": "ecg.exchange",
+                "halo_unpack": "ecg.exchange"}
+
+
+@pytest.fixture(scope="module")
+def lowered_schemes():
+    """devices -> {method: compiled HLO text}, each device count lowered
+    once in a child process (which fixes its own CPU device count)."""
+    root = Path(__file__).resolve().parents[1]
+    cache = {}
+
+    def get(devices):
+        if devices not in cache:
+            env = dict(os.environ, JAX_PLATFORMS="cpu",
+                       PYTHONPATH=str(root / "src"),
+                       XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
+            out = subprocess.run([sys.executable, "-c", _LOWER_SCHEMES, str(devices)],
+                                 env=env, capture_output=True, text=True, timeout=600)
+            assert out.returncode == 0, out.stderr[-4000:]
+            cache[devices] = json.loads(out.stdout.strip().splitlines()[-1])
+        return cache[devices]
+
+    return get
+
+
+def _stages(op_name: str) -> list[str]:
+    return [c for c in op_name.split("/") if c.startswith("ecg.")]
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+@pytest.mark.parametrize("method", ["classic", "pipelined", "sstep"])
+def test_loop_body_carries_stage_scopes(lowered_schemes, method, devices):
+    """Every kernel, collective, Cholesky and triangular solve of the
+    compiled loop body names its stage; the stages present are the
+    iteration's, the exchange only across devices and inside the SpMBV."""
+    from chipbench import trace as tr
+
+    hlo = tr.HloIndex(lowered_schemes(devices)[method])
+    body = [i for m in hlo.by_module.values() for i in m.values()
+            if re.match(r"jit\(\w+\)/while/body/", i.op_name)]
+    kernels, collectives = set(), 0
+    for i in body:
+        stages = _stages(i.op_name)
+        comps = i.op_name.split("/")
+        for kernel, stage in KERNEL_STAGE.items():
+            if kernel in comps:
+                kernels.add(kernel)
+                assert stages[-1:] == [stage], i
+        if i.opcode.startswith("all-reduce"):
+            collectives += 1
+            assert stages[-1:] in (["ecg.gram"], ["ecg.check"]), i
+        if i.opcode.startswith("collective-permute"):
+            collectives += 1
+            assert stages[-1:] == ["ecg.exchange"], i
+        if "potrf" in i.target or "trsm" in i.target or i.opcode in (
+                "cholesky", "triangular-solve"):
+            assert stages[-1:] == ["ecg.factor"], i
+        if "ecg.exchange" in stages:
+            assert "ecg.spmbv" in stages[:stages.index("ecg.exchange")], i
+    present = {s for i in body for s in _stages(i.op_name)}
+    expected = {"ecg.spmbv", "ecg.gram", "ecg.factor", "ecg.update", "ecg.check"}
+    assert present == expected | ({"ecg.exchange"} if devices > 1 else set())
+    want_kernels = {"bsr_spmbv"}
+    if method != "sstep":  # s-step reduces mixed widths on the jnp path
+        want_kernels |= {"fused_gram", "ecg_tail"}
+    if devices > 1:
+        want_kernels |= {"halo_pack", "halo_unpack"}
+    assert kernels == want_kernels
+    assert (collectives > 0) == (devices > 1)

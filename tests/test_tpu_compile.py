@@ -107,3 +107,38 @@ def test_block_trisolve_compiles(one_chip, chip_mode):
 
     _compile(block_trisolve_pallas, one_chip,
              ((NBR, TILE, TILE), F32), ((NBR, TILE, T), F32))
+
+
+def test_solve_program_names_its_stages(one_chip, chip_mode, monkeypatch):
+    """The whole solve program, compiled for the chip: each kernel's
+    custom call carries its stage's scope and keeps the name the
+    benchmark's readers select on (a small DG operator; the stages do not
+    depend on its size)."""
+    import importlib
+    import re
+
+    from repro.solver import ECGSolver, KernelConfig, SolverConfig
+    from repro.sparse.matrices import dg_laplace_2d
+
+    for name in ("bsr_spmbv", "fused_gram", "block_update"):
+        # the kernels as the chip runs them: this host's backend is the CPU
+        monkeypatch.setattr(importlib.import_module(f"repro.kernels.{name}.ops"),
+                            "resolve_dispatch", lambda op, use: (True, False))
+    a = dg_laplace_2d((8, 8), block=TILE, dtype=F32)
+    solver = ECGSolver.build(a, None, SolverConfig(
+        t=T, tol=1e-4, max_iters=100, tune="off",
+        kernel=KernelConfig(backend="pallas", ell_block=(TILE, TILE))))
+    vec = jax.ShapeDtypeStruct((a.shape[0],), F32, sharding=one_chip)
+    fn, lifted = solver._jit(T, "fresh")._entry((vec, vec))
+    consts = [jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip) for x in lifted]
+    hlo = fn.lower(consts, vec, vec).compile().as_text()
+    calls = {}
+    for line in hlo.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            op_name = re.search(r'op_name="([^"]*)"', line)[1]
+            if "/while/body/" in op_name:
+                calls[op_name.split("/")[-2]] = op_name.split("/")
+    assert set(calls) == {"bsr_spmbv", "fused_gram", "ecg_tail"}
+    assert "ecg.spmbv" in calls["bsr_spmbv"] and "jit(bsr_spmbv_pallas)" in calls["bsr_spmbv"]
+    assert "ecg.gram" in calls["fused_gram"]
+    assert "ecg.update" in calls["ecg_tail"]
