@@ -13,7 +13,8 @@ from repro.kernels.block_update.ref import block_update_ref, ecg_tail_ref
 from repro.kernels.dispatch import resolve_dispatch
 
 
-def block_update(x, r, p, ap, c, use_pallas: bool | None = None, block_rows: int = 512):
+def block_update(x, r, p, ap, c, use_pallas: bool | None = None,
+                 block_rows: int | None = None):
     use_pallas, interpret = resolve_dispatch("block_update", use_pallas)
     if use_pallas:
         return block_update_pallas(x, r, p, ap, c, block_rows=block_rows, interpret=interpret)
@@ -21,7 +22,7 @@ def block_update(x, r, p, ap, c, use_pallas: bool | None = None, block_rows: int
 
 
 def ecg_tail(x, r, p, ap, p_old, c, d, d_old, use_pallas: bool | None = None,
-             block_rows: int = 512):
+             block_rows: int | None = None):
     """Fused tail of one ECG iteration; see :func:`ecg_tail_ref` for the math."""
     use_pallas, interpret = resolve_dispatch("ecg_tail", use_pallas)
     if use_pallas:

@@ -6,13 +6,17 @@ from HBM in three separate GEMM passes (AP twice); this kernel streams each
 operand tile exactly once — the local-compute counterpart of the paper's
 "fuse the reductions" discipline (§3.1): one HBM pass feeding one allreduce.
 
-Memory-bound analysis (per n-row shard, bf16/f32):
+Memory-bound analysis (per n-row shard, f32):
     naive:  reads P, R, 2·AP, AP_old  = 5·n·t·f bytes
     fused:  reads P, R, AP, AP_old    = 4·n·t·f bytes   (1.25x traffic cut)
+The operands are the (t, n) views of :mod:`repro.kernels.lanes`, the layout
+XLA already keeps the block vectors in, so those 4·n·t·f bytes are all the
+call moves: no relayout copy on the way in.
 
-Grid: 1-D over row tiles of the lane-dense views (:mod:`repro.kernels.lanes`);
-the (L, 3L) accumulator of lane-row Grams lives in the revisited output
-block (VMEM-resident across the whole grid) and is folded to (t, 3t) after.
+Grid: 1-D over (t, L) blocks; each block adds three NT products contracting
+over its lanes to the (3, t, t) accumulator, the revisited output block
+(VMEM-resident across the whole grid).  Lanes past n in the ragged last
+block are masked before they are summed.
 """
 
 from __future__ import annotations
@@ -23,12 +27,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.lanes import diag_sum, fold_width, step_rows, to_lanes
+from repro.kernels.lanes import lane_block
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _kernel(p_ref, r_ref, ap_ref, apo_ref, out_ref):
+def _kernel(n, p_ref, r_ref, ap_ref, apo_ref, out_ref):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -36,30 +40,34 @@ def _kernel(p_ref, r_ref, ap_ref, apo_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     acc = out_ref.dtype
-    gram = lambda a, b: jnp.dot(a.T, b, precision=_HIGHEST, preferred_element_type=acc)
-    ap = ap_ref[...]
-    out_ref[...] += jnp.concatenate(
-        [gram(p_ref[...], r_ref[...]), gram(ap, ap), gram(apo_ref[...], ap)], axis=1
+    t, rows = p_ref.shape
+    ops = [ref[...].astype(acc) for ref in (p_ref, r_ref, ap_ref, apo_ref)]
+    if n % rows:
+        live = i * rows + jax.lax.broadcasted_iota(jnp.int32, (t, rows), 1) < n
+        ops = [jnp.where(live, x, 0) for x in ops]
+    p, r, ap, apo = ops
+    gram = lambda a, b: jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())), precision=_HIGHEST, preferred_element_type=acc
     )
+    out_ref[0] += gram(p, r)
+    out_ref[1] += gram(ap, ap)
+    out_ref[2] += gram(apo, ap)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def fused_gram_pallas(p, r, ap, ap_old, *, block_rows: int = 512, interpret: bool = False):
+def fused_gram_pallas(p, r, ap, ap_old, *, block_rows: int | None = None,
+                      interpret: bool = False):
     n, t = p.shape
-    tp, fold = fold_width(t)
-    lanes = tp * fold
-    rows = step_rows(n, fold, block_rows)
-    ops = [to_lanes(x, tp, fold, rows) for x in (p, r, ap, ap_old)]
+    rows = lane_block(n, t, p.dtype, block_rows)
     acc = jnp.float64 if p.dtype == jnp.float64 else jnp.float32
-    spec = pl.BlockSpec((rows, lanes), lambda i: (i, 0))
+    spec = pl.BlockSpec((t, rows), lambda i: (0, i))
     g = pl.pallas_call(
-        _kernel,
-        grid=(ops[0].shape[0] // rows,),
-        in_specs=[spec, spec, spec, spec],
-        out_specs=pl.BlockSpec((lanes, 3 * lanes), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((lanes, 3 * lanes), acc),
+        functools.partial(_kernel, n),
+        grid=(pl.cdiv(n, rows),),
+        in_specs=[spec] * 4,
+        out_specs=pl.BlockSpec((3, t, t), lambda i: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((3, t, t), acc),
         interpret=interpret,
         name="fused_gram",
-    )(*ops)
-    parts = [diag_sum(g[:, k * lanes : (k + 1) * lanes], t, tp, fold) for k in range(3)]
-    return jnp.concatenate(parts, axis=1).astype(p.dtype)
+    )(p.T, r.T, ap.T, ap_old.T)
+    return jnp.concatenate(list(g), axis=1).astype(p.dtype)

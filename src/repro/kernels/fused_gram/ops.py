@@ -13,7 +13,8 @@ from repro.kernels.fused_gram.kernel import fused_gram_pallas
 from repro.kernels.fused_gram.ref import fused_gram_ref
 
 
-def fused_gram(p, r, ap, ap_old, use_pallas: bool | None = None, block_rows: int = 512):
+def fused_gram(p, r, ap, ap_old, use_pallas: bool | None = None,
+               block_rows: int | None = None):
     use_pallas, interpret = resolve_dispatch("fused_gram", use_pallas)
     if use_pallas:
         return fused_gram_pallas(p, r, ap, ap_old, block_rows=block_rows, interpret=interpret)

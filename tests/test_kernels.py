@@ -176,3 +176,65 @@ class TestHotPathSweeps:
             np.testing.assert_allclose(
                 np.asarray(g, np.float64), np.asarray(w, np.float64), **sweep_tol(dtype)
             )
+
+
+# ---------------------------------------------------------------------------
+# edges of the (t, n) view: a ragged last block over several grid steps (the
+# Gram's lane mask: interpret mode pads blocks with NaN), CG width t = 1, the
+# packed widths 16 and 24, and the default lane step
+# ---------------------------------------------------------------------------
+TN_EDGES = [(1000, 8, 128), (1000, 1, 128), (1000, 16, 256), (1000, 24, 384),
+            (20000, 8, None), (300, 24, None)]
+
+
+class TestTnView:
+    @pytest.mark.parametrize("dtype", SWEEP_DTYPES)
+    @pytest.mark.parametrize("n,t,block_rows", TN_EDGES)
+    def test_fused_gram_edges(self, rng, n, t, block_rows, dtype):
+        mats = [jnp.asarray(rng.standard_normal((n, t)), dtype) for _ in range(4)]
+        got = fused_gram_pallas(*mats, block_rows=block_rows, interpret=True)
+        want = fused_gram_ref(*mats)
+        assert got.shape == (t, 3 * t) and got.dtype == dtype
+        np.testing.assert_allclose(
+            np.asarray(got, np.float64), np.asarray(want, np.float64),
+            **(dict(rtol=1e-12, atol=1e-10) if dtype == jnp.float64
+               else dict(rtol=1e-4, atol=2e-3)),
+        )
+
+    @pytest.mark.parametrize("dtype", SWEEP_DTYPES)
+    @pytest.mark.parametrize("n,t,block_rows", TN_EDGES)
+    def test_ecg_tail_edges(self, rng, n, t, block_rows, dtype):
+        x, r, p, ap, po = (jnp.asarray(rng.standard_normal((n, t)), dtype) for _ in range(5))
+        c, d, do = (jnp.asarray(rng.standard_normal((t, t)), dtype) for _ in range(3))
+        got = ecg_tail_pallas(x, r, p, ap, po, c, d, do, block_rows=block_rows, interpret=True)
+        want = ecg_tail_ref(x, r, p, ap, po, c, d, do)
+        for g, w in zip(got, want):
+            assert g.shape == (n, t) and g.dtype == dtype
+            np.testing.assert_allclose(
+                np.asarray(g, np.float64), np.asarray(w, np.float64), **sweep_tol(dtype)
+            )
+
+    @pytest.mark.parametrize("n,t,block_rows", [(1000, 8, 128), (1000, 16, 256)])
+    def test_block_update_edges(self, rng, n, t, block_rows):
+        x, r, p, ap = (jnp.asarray(rng.standard_normal((n, t)), jnp.float64) for _ in range(4))
+        c = jnp.asarray(rng.standard_normal((t, t)), jnp.float64)
+        got = block_update_pallas(x, r, p, ap, c, block_rows=block_rows, interpret=True)
+        for g, w in zip(got, block_update_ref(x, r, p, ap, c)):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w), **sweep_tol(jnp.float64))
+
+    @pytest.mark.parametrize("n,t,dtype,block_rows,want", [
+        (1000, 8, jnp.float32, 128, 128),        # a multiple of 128 stays
+        (1000, 8, jnp.float32, 100, 128),        # rounded up to one
+        (1000, 8, jnp.float32, 1000, 1000),      # covers n: all of it
+        (1000, 8, jnp.float32, 960, 1000),       # rounded up past n
+        (1310720, 8, jnp.float32, None, 16384),  # (8, 16384) f32: 512 KiB
+        (1310720, 1, jnp.float32, None, 16384),  # t pads to 8 sublanes
+        (1310720, 16, jnp.float32, None, 8192),
+        (1310720, 24, jnp.float32, None, 5376),  # rounded down to 128s
+        (1310720, 8, jnp.bfloat16, None, 16384),  # bf16 packs 16 sublanes
+        (5000, 8, jnp.float32, None, 5000),
+    ])
+    def test_lane_block(self, n, t, dtype, block_rows, want):
+        from repro.kernels.lanes import lane_block
+
+        assert lane_block(n, t, dtype, block_rows) == want

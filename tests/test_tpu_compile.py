@@ -142,3 +142,53 @@ def test_solve_program_names_its_stages(one_chip, chip_mode, monkeypatch):
     assert "ecg.spmbv" in calls["bsr_spmbv"] and "jit(bsr_spmbv_pallas)" in calls["bsr_spmbv"]
     assert "ecg.gram" in calls["fused_gram"]
     assert "ecg.update" in calls["ecg_tail"]
+
+
+def _instructions(hlo):
+    """{name: (result shape with layout, opcode, operand names)} of every
+    instruction of the compiled text; layouts without their tiling."""
+    import re
+
+    out = {}
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*)", line)
+        op = m and re.search(r"\s([a-z][\w\-]*)\(([^)]*)\)", m[2])
+        if not op:
+            continue
+        ty = re.sub(r":[^}]*\}", "}", m[2][: op.start()].strip())
+        out[m[1]] = (ty, op[1], re.findall(r"%([\w.\-]+)", op[2]), line)
+    return out
+
+
+def test_block_vector_kernels_take_xla_layout(one_chip, chip_mode):
+    """Ten steps whose five (N, T) carries go through the ``ecg_tail`` and
+    ``fused_gram`` kernels compile with no relayout of a block vector: XLA
+    keeps an (N, 8) array as (8, N) row-major, the view the kernels read,
+    so every operand of the two custom calls is a bitcast, a parameter, a
+    tuple element or a copy that keeps its layout, and no program value is
+    an (N/16, 16, 8) fold view or an (N, 8) array laid out row-major."""
+    from repro.kernels.block_update.kernel import ecg_tail_pallas
+    from repro.kernels.fused_gram.kernel import fused_gram_pallas
+
+    def run(x, r, p, ap, po, c, d, do):
+        def body(i, carry):
+            x, r, p, ap, po, g = carry
+            x, r, z = ecg_tail_pallas(x, r, p, ap, po, c, d, do)
+            return x, r, z, ap * 1.5, p, g + fused_gram_pallas(p, r, ap, po)
+
+        g = jnp.zeros((T, 3 * T), F32)
+        return jax.lax.fori_loop(0, 10, body, (x, r, p, ap, po, g))
+
+    ins = _instructions(_compile(run, one_chip, *[((N, T), F32)] * 5, *[((T, T), F32)] * 3))
+    for ty, _, _, line in ins.values():
+        assert f"[{NBR}," not in ty and f"f32[{N},{T}]{{1,0}}" not in ty, line
+    kernels = {}
+    for name, (ty, opcode, operands, line) in ins.items():
+        if opcode == "custom-call" and 'custom_call_target="tpu_custom_call"' in line:
+            kernels[name] = operands
+            for operand in operands:
+                oty, oop, src, oline = ins[operand]
+                same = oop == "copy" and ins[src[0]][0] == oty
+                assert oop in ("bitcast", "parameter", "get-tuple-element") or same, oline
+    assert sorted(k.split(".")[0] for k in kernels) == ["ecg_tail", "fused_gram"]
+    assert [len(v) for k, v in sorted(kernels.items())] == [8, 4]
