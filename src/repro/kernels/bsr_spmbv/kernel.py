@@ -34,6 +34,7 @@ from jax.experimental import pallas as pl
 
 # block rows per lane group of the operator layout (the TPU lane width)
 from repro.kernels.lanes import LANES
+from repro.observe.scopes import GATHER
 
 
 def _acc_dtype(dtype):
@@ -61,18 +62,19 @@ def _kernel(a_ref, v_ref, out_ref):
 def _gather_lanes(v, indices, bc: int, nbg: int):
     """V rows each block row's tiles need, block rows on the lanes:
     ``(nbg, kmax·bc, t, LANES)`` (padded block rows gather block column 0
-    and meet zero tiles)."""
+    and meet zero tiles), traced under the ``ecg.gather`` scope."""
     nbr, kmax = indices.shape
     t = v.shape[1]
-    idx = jnp.pad(indices, ((0, nbg * LANES - nbr), (0, 0)))
-    # gather whole (bc·t)-wide rows (one per block column), then swap the
-    # block-row axis onto the lanes: (bc·t, LANES) transposes.  The rows are
-    # spelled from Vᵀ, the layout XLA keeps a narrow (n, t) array in; a
-    # plain ``v.reshape`` relayouts V through a lane-padded copy first.
-    rows = v.T.reshape(t, -1, bc).transpose(1, 2, 0).reshape(-1, bc * t)
-    vg = rows[idx]  # (nbg·L, kmax, bc·t)
-    vg = vg.reshape(nbg, LANES, kmax, bc * t).transpose(0, 2, 3, 1)
-    return vg.reshape(nbg, kmax * bc, t, LANES)
+    with jax.named_scope(GATHER):
+        idx = jnp.pad(indices, ((0, nbg * LANES - nbr), (0, 0)))
+        # gather whole (bc·t)-wide rows (one per block column), then swap the
+        # block-row axis onto the lanes: (bc·t, LANES) transposes.  The rows
+        # are spelled from Vᵀ, the layout XLA keeps a narrow (n, t) array in;
+        # a plain ``v.reshape`` relayouts V through a lane-padded copy first.
+        rows = v.T.reshape(t, -1, bc).transpose(1, 2, 0).reshape(-1, bc * t)
+        vg = rows[idx]  # (nbg·L, kmax, bc·t)
+        vg = vg.reshape(nbg, LANES, kmax, bc * t).transpose(0, 2, 3, 1)
+        return vg.reshape(nbg, kmax * bc, t, LANES)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

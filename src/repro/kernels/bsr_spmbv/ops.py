@@ -74,15 +74,44 @@ def _tile_keys(indptr, indices, n_rows: int, n_cols: int, br: int, bc: int):
     return rows, key, head, np.unique(key[head])
 
 
-def count_block_ell_tiles(indptr, indices, n_rows: int, n_cols: int, br: int, bc: int) -> int:
-    """Max distinct (br x bc) tiles in any block row of a raw-CSR matrix."""
+def block_ell_tile_keys(indptr, indices, n_rows: int, n_cols: int, br: int, bc: int):
+    """Sorted distinct tile keys ``block_row * nbc + block_col`` of a
+    raw-CSR matrix's (br x bc) tiles."""
     indptr = np.asarray(indptr, dtype=np.int64)
     indices = np.asarray(indices, dtype=np.int64)
     if int(indptr[min(n_rows, len(indptr) - 1)]) == 0:
-        return 0
+        return np.zeros(0, np.int64)
+    return _tile_keys(indptr, indices, n_rows, n_cols, br, bc)[3]
+
+
+def count_block_ell_tiles(indptr, indices, n_rows: int, n_cols: int, br: int, bc: int) -> int:
+    """Max distinct (br x bc) tiles in any block row of a raw-CSR matrix."""
     nbc = (n_cols + bc - 1) // bc
-    tiles = _tile_keys(indptr, indices, n_rows, n_cols, br, bc)[3]
-    return int(np.bincount(tiles // nbc).max())
+    tiles = block_ell_tile_keys(indptr, indices, n_rows, n_cols, br, bc)
+    return int(np.bincount(tiles // nbc).max()) if len(tiles) else 0
+
+
+def tile_counters(keys, nbc: int, nbr: int, kmax: int) -> dict:
+    """The structure of a Block-ELL layout, as the counters its conversion
+    span records; ``keys`` holds the sorted tile keys of each stacked part
+    (one per device), each part ``nbr`` block rows of ``kmax`` slots:
+
+    * ``ell_kmax``: tile slots per block row;
+    * ``ell_tiles``: tiles stored, over all parts;
+    * ``ell_zero_slot_share``: the share of the parts' ``nbr · kmax`` slots
+      that hold no tile (a zero tile the kernel streams all the same);
+    * ``gather_reach``: the largest distance, in block rows, between a
+      tile's block column and its block row — how far apart the rows of V
+      lie that the SpMBV's gather reads for neighbouring block rows.
+    """
+    tiles = sum(len(k) for k in keys)
+    slots = len(keys) * nbr * kmax
+    reach = max((int(np.abs(k % nbc - k // nbc).max()) for k in keys if len(k)), default=0)
+    return dict(
+        ell_kmax=int(kmax), ell_tiles=int(tiles),
+        ell_zero_slot_share=1.0 - tiles / slots if slots else 0.0,
+        gather_reach=reach,
+    )
 
 
 def csr_arrays_to_block_ell(
@@ -139,7 +168,8 @@ def block_ell_meta(a: CSRMatrix, br: int, bc: int) -> dict:
     lets a rebuilt handle skip the analysis pass and direct-fill via
     :func:`csr_arrays_to_block_ell` (the serve layer's eviction-aware warm
     start).  ``pad_hist[k]`` counts block rows holding exactly k tiles —
-    the padding histogram behind the ``kmax`` waste.
+    the padding histogram behind the ``kmax`` waste; ``counters`` are
+    :func:`tile_counters`.
     """
     indptr = np.asarray(a.indptr, dtype=np.int64)
     indices = np.asarray(a.indices, dtype=np.int64)
@@ -155,6 +185,7 @@ def block_ell_meta(a: CSRMatrix, br: int, bc: int) -> dict:
         nbr=int(nbr), nbc=int(nbc), kmax=kmax,
         n_pad=int(n_pad), m_pad=int(m_pad),
         pad_hist=np.bincount(per_row, minlength=kmax + 1).tolist(),
+        counters=tile_counters([tiles], nbc, nbr, kmax),
     )
 
 

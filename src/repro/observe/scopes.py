@@ -10,7 +10,8 @@ The scopes are opened where the stages are built once, so that every
 iteration scheme gets them: the reduction and update closures of
 :func:`repro.core.ecg.make_ecg_runner`, the factorizations
 (``_chol_inv_apply``, ``rank_revealing_apply``), the breakdown guard of
-``_guarded_while`` and the distributed halo exchange.  The glue between
+``_guarded_while``, the distributed halo exchange and the SpMBV kernel's
+gather of V (``_gather_lanes``).  The glue between
 stages inside each scheme's ``iterate`` is left unscoped.
 """
 
@@ -19,6 +20,7 @@ from __future__ import annotations
 import jax
 
 SPMBV = "ecg.spmbv"        # gather of V, the Block-ELL kernel, local/remote split
+GATHER = "ecg.gather"      # the gather of V into the kernel's layout (inside SPMBV)
 EXCHANGE = "ecg.exchange"  # halo pack, ppermute rounds, unpack (inside SPMBV)
 GRAM = "ecg.gram"          # the Gram products and their psums
 FACTOR = "ecg.factor"      # t x t (pivoted) Cholesky and the TRSMs

@@ -216,6 +216,7 @@ class ECGSolver:
                 sp.args.update(
                     analyzed=self.stats.conv_analyzed,
                     reused=self.stats.conv_reused,
+                    **(self.conversion["meta"] or {}).get("counters", {}),
                 )
         else:
             self._apply = lambda V: csr_spmbv(self.a, V)
@@ -349,6 +350,7 @@ class ECGSolver:
                 tuned_strategy=(
                     self.op.tuned.strategy if self.op.tuned else strategy
                 ),
+                **self.op.ell_counters,
             )
         self.stats.builds += 1
         if self.selection is not None and self.op.tuned is not None:

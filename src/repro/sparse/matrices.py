@@ -15,6 +15,7 @@ published rows / nnz-per-row / density (see DESIGN.md §5).
 from __future__ import annotations
 
 import dataclasses
+import zlib
 
 import numpy as np
 import jax.numpy as jnp
@@ -288,6 +289,12 @@ SUITE_MATRICES: dict[str, SuiteSpec] = {
 EXAMPLE_2_1 = dict(elements=(320, 256), block=16)
 
 
+def surrogate_perm_seed(name: str) -> int:
+    """Seed of a surrogate's window shuffle: the CRC-32 of its name, the
+    same in every process, where Python salts the hash of a ``str``."""
+    return zlib.crc32(name.encode()) & 0x7FFFFFFF
+
+
 def suite_surrogate(name: str, scale: float = 1.0, dtype=jnp.float64) -> CSRMatrix:
     """Structural surrogate of a Table-3 matrix (optionally scaled down).
 
@@ -304,7 +311,7 @@ def suite_surrogate(name: str, scale: float = 1.0, dtype=jnp.float64) -> CSRMatr
         n = grid[0] * grid[1]
     if spec.window:
         window = max(16, int(spec.window * scale))
-        perm = window_shuffle_perm(n, window, seed=hash(name) % 2**31)
+        perm = window_shuffle_perm(n, window, seed=surrogate_perm_seed(name))
         indptr, cols, vals = _permute_graph(indptr, cols, vals, n, perm)
     if spec.block == 1:
         return CSRMatrix(
@@ -334,7 +341,7 @@ def surrogate_graph(name: str, scale: float = 1.0) -> tuple[CSRMatrix, int]:
     n = int(np.prod(grid))
     if spec.window:
         window = max(16, int(spec.window * scale))
-        perm = window_shuffle_perm(n, window, seed=hash(name) % 2**31)
+        perm = window_shuffle_perm(n, window, seed=surrogate_perm_seed(name))
         indptr, cols, vals = _permute_graph(indptr, cols, vals, n, perm)
     g = CSRMatrix(
         indptr=jnp.asarray(indptr, jnp.int32),

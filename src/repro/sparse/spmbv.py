@@ -82,9 +82,10 @@ from repro.sparse.partition import (
 from repro.core.node_aware import ExchangePlan, build_exchange_plan
 from repro.kernels.bsr_spmbv.kernel import LANES
 from repro.kernels.bsr_spmbv.ops import (
+    block_ell_tile_keys,
     bsr_spmbv,
-    count_block_ell_tiles,
     csr_arrays_to_block_ell,
+    tile_counters,
 )
 from repro.kernels.halo_pack.ops import halo_pack, halo_unpack
 from repro.observe import scopes
@@ -118,8 +119,10 @@ class DistributedSpMBV:
     backend: str = "jnp"
     overlap: bool = False
     ell_block: int | tuple[int, int] = 8  # Block-ELL tile shape (br, bc)
-    # pallas blocking path: Block-ELL of the full [own ‖ halo] local block
+    # pallas blocking path: Block-ELL of the full [own ‖ halo] local block,
+    # and its structure (kernels.bsr_spmbv.ops.tile_counters)
     ell: dict = dataclasses.field(default_factory=dict)
+    ell_counters: dict = dataclasses.field(default_factory=dict)
     # overlap path: interior/boundary structures (CSR or Block-ELL per backend)
     split: dict = dataclasses.field(default_factory=dict)
     # TunedConfig when the operator was built via tune= (None otherwise)
@@ -419,20 +422,20 @@ def _stack_gathered_csr(per_rank, n_rows_max, rmax, dtype):
 
 
 def _stack_block_ell(per_rank, n_rows_max, n_cols, br, bc, dtype):
-    """Convert per-rank gathered CSR triples to one stacked Block-ELL array."""
+    """Convert per-rank gathered CSR triples to one stacked Block-ELL array;
+    returns ``(blocks, idx, counters)`` (:func:`tile_counters`)."""
     p = len(per_rank)
     nbr = max(1, (n_rows_max + br - 1) // br)
-    kmax = max(
-        [count_block_ell_tiles(g[1], g[2], len(g[0]), n_cols, br, bc) for g in per_rank]
-        + [1]
-    )
+    nbc = (n_cols + bc - 1) // bc
+    keys = [block_ell_tile_keys(g[1], g[2], len(g[0]), n_cols, br, bc) for g in per_rank]
+    kmax = max([int(np.bincount(k // nbc).max()) for k in keys if len(k)] + [1])
     blocks = np.zeros((p, max(1, -(-nbr // LANES)), kmax * bc, br, LANES), dtype)
     idx = np.zeros((p, nbr, kmax), np.int32)
     for r, (rows, gptr, gix, gdat) in enumerate(per_rank):
         blocks[r], idx[r] = csr_arrays_to_block_ell(
             gptr, gix, gdat, len(rows), n_cols, br, bc, nbr, kmax
         )
-    return blocks, idx
+    return blocks, idx, tile_counters(keys, nbc, nbr, kmax)
 
 
 def make_distributed_spmbv(
@@ -559,12 +562,14 @@ def _make_distributed_spmbv(
     n_cols_full = rmax + plan.halo_rows
     br, bc = (ell_block, ell_block) if isinstance(ell_block, int) else ell_block
 
-    ell = {}
+    ell, ell_counters = {}, {}
     if backend == "pallas" and not overlap:
         per_rank = [
             (np.arange(n_local), ptr, ix, dat) for ptr, ix, dat, n_local in rebased
         ]
-        blocks, idx = _stack_block_ell(per_rank, rmax, n_cols_full, br, bc, val_dtype)
+        blocks, idx, ell_counters = _stack_block_ell(
+            per_rank, rmax, n_cols_full, br, bc, val_dtype
+        )
         ell = {"blocks": blocks, "indices": idx}
 
     split = {}
@@ -588,10 +593,10 @@ def _make_distributed_spmbv(
         )
         split = {"int_rows": int_ids, "bnd_rows": bnd_ids}
         if backend == "pallas":
-            split["int_blocks"], split["int_idx"] = _stack_block_ell(
+            split["int_blocks"], split["int_idx"], _ = _stack_block_ell(
                 int_per_rank, n_int_max, rmax, br, bc, val_dtype
             )
-            split["bnd_blocks"], split["bnd_idx"] = _stack_block_ell(
+            split["bnd_blocks"], split["bnd_idx"], _ = _stack_block_ell(
                 bnd_per_rank, n_bnd_max, n_cols_full, br, bc, val_dtype
             )
         else:
@@ -617,6 +622,7 @@ def _make_distributed_spmbv(
         overlap=overlap,
         ell_block=(br, bc),
         ell={k2: put(v) for k2, v in ell.items()},
+        ell_counters=ell_counters,
         split={k2: put(v) for k2, v in split.items()},
         tuned=tuned,
     )

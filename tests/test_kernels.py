@@ -82,7 +82,7 @@ class TestBsrSpmbv:
                 per_rank.append((rows, indptr[rows[0]: rows[-1] + 2] - lo,
                                  indices_all[lo:hi], data[lo:hi]))
             n_rows_max = max(len(r[0]) for r in per_rank)
-            blocks, idx = _stack_block_ell(per_rank, n_rows_max, n, bs, bs, np.float64)
+            blocks, idx, _ = _stack_block_ell(per_rank, n_rows_max, n, bs, bs, np.float64)
             assert idx.shape[1] > 128
             parts = [(rows, jnp.asarray(blocks[r]), jnp.asarray(idx[r]))
                      for r, (rows, *_) in enumerate(per_rank)]
@@ -238,3 +238,58 @@ class TestTnView:
         from repro.kernels.lanes import lane_block
 
         assert lane_block(n, t, dtype, block_rows) == want
+
+
+class TestWindowShuffled:
+    """The Table-3 audikw_1 surrogate: element ids shuffled within windows
+    (neighbour tiles scattered across the block columns, not in order as in
+    DG) and a block-row count that leaves the last lane group ragged."""
+
+    def test_spmbv_against_ref(self, rng):
+        from repro.kernels.bsr_spmbv.ops import block_ell_arrays
+        from repro.sparse import suite_surrogate
+
+        a = suite_surrogate("audikw_1", scale=0.05)  # 12 x 12 elements of 16
+        blocks, indices, m_pad, meta, _ = block_ell_arrays(a, 16, 16)
+        assert meta["nbr"] == 144 and meta["nbr"] % 128 and meta["kmax"] == 5
+        v = jnp.asarray(rng.standard_normal((m_pad, 8)))
+        got = bsr_spmbv_pallas(blocks, indices, v, interpret=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(bsr_spmbv_ref(blocks, indices, v)),
+                                   rtol=1e-12, atol=1e-12)
+        ad = np.asarray(a.todense(), np.float64)
+        np.testing.assert_allclose(np.asarray(got), ad @ np.asarray(v), rtol=1e-12, atol=1e-12)
+
+    def test_convert_span_counters(self):
+        from repro.observe import MemorySink, Tracer
+        from repro.solver import ECGSolver, KernelConfig, SolverConfig
+        from repro.sparse import suite_surrogate
+        from repro.sparse.spmbv import _stack_block_ell
+
+        def counters(a):
+            sink = MemorySink()
+            ECGSolver.build(a, None, SolverConfig(
+                t=4, tol=1e-8, max_iters=10, tune="off",
+                kernel=KernelConfig(backend="pallas", ell_block=(16, 16))),
+                tracer=Tracer(sinks=[sink]))
+            (span,) = [s for s in sink.spans if s.name == "build/convert"]
+            return span.args
+
+        dg = dg_laplace_2d((8, 8), block=16)
+        # 64 elements of 5 tiles, less the 2 * (8 + 8) missing boundary neighbours
+        got = counters(dg)
+        assert got["ell_kmax"] == 5 and got["ell_tiles"] == 288
+        assert got["ell_zero_slot_share"] == pytest.approx(32 / 320)
+        assert got["gather_reach"] == 8  # the element grid's second side
+        shuffled = counters(suite_surrogate("audikw_1", scale=8 / 243))  # 8 x 8 elements
+        assert shuffled["ell_kmax"] == 5 and shuffled["ell_tiles"] == 288
+        assert shuffled["gather_reach"] > 8
+        # the distributed conversion's counters over two parts of the rows;
+        # each part numbers its block rows from 0 and here keeps the global
+        # columns, so the second part's gather reaches 32 + 8 block rows
+        n, ptr, ix = dg.shape[0], np.asarray(dg.indptr), np.asarray(dg.indices)
+        per_rank = [(rows, ptr[rows[0]: rows[-1] + 2] - ptr[rows[0]],
+                     ix[ptr[rows[0]]: ptr[rows[-1] + 1]], np.ones(ptr[rows[-1] + 1] - ptr[rows[0]]))
+                    for rows in np.array_split(np.arange(n), 2)]
+        *_, parts = _stack_block_ell(per_rank, n // 2, n, 16, 16, np.float64)
+        assert parts == dict(ell_kmax=5, ell_tiles=288, gather_reach=40,
+                             ell_zero_slot_share=pytest.approx(1 - 288 / (2 * 32 * 5)))

@@ -575,7 +575,8 @@ def _stages(op_name: str) -> list[str]:
 def test_loop_body_carries_stage_scopes(lowered_schemes, method, devices):
     """Every kernel, collective, Cholesky and triangular solve of the
     compiled loop body names its stage; the stages present are the
-    iteration's, the exchange only across devices and inside the SpMBV."""
+    iteration's, the exchange only across devices, and the exchange and the
+    gather of V inside the SpMBV."""
     from chipbench import trace as tr
 
     hlo = tr.HloIndex(lowered_schemes(devices)[method])
@@ -598,10 +599,12 @@ def test_loop_body_carries_stage_scopes(lowered_schemes, method, devices):
         if "potrf" in i.target or "trsm" in i.target or i.opcode in (
                 "cholesky", "triangular-solve"):
             assert stages[-1:] == ["ecg.factor"], i
-        if "ecg.exchange" in stages:
-            assert "ecg.spmbv" in stages[:stages.index("ecg.exchange")], i
+        for inner in ("ecg.exchange", "ecg.gather"):
+            if inner in stages:
+                assert "ecg.spmbv" in stages[:stages.index(inner)], i
     present = {s for i in body for s in _stages(i.op_name)}
-    expected = {"ecg.spmbv", "ecg.gram", "ecg.factor", "ecg.update", "ecg.check"}
+    expected = {"ecg.spmbv", "ecg.gather", "ecg.gram", "ecg.factor", "ecg.update",
+                "ecg.check"}
     assert present == expected | ({"ecg.exchange"} if devices > 1 else set())
     want_kernels = {"bsr_spmbv"}
     if method != "sstep":  # s-step reduces mixed widths on the jnp path

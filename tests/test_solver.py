@@ -402,3 +402,35 @@ class TestSolvePacked:
             solver.solve_packed([])
         with pytest.raises(ValueError, match="guesses"):
             solver.solve_packed(bs, x0s=[None, None])
+
+
+class TestTable3Surrogate:
+    """The paper's Table 3 audikw_1, as the repo's window-shuffled surrogate
+    at a small scale (144 block rows: a ragged last lane group), solved
+    through ``ECGSolver.build``/``solve`` with every kernel in Pallas
+    interpret mode, against a plain float64 solve of the same CSR."""
+
+    def test_pallas_solve_against_float64_reference(self, monkeypatch):
+        import importlib
+
+        from repro.sparse import suite_surrogate
+
+        for name in ("bsr_spmbv", "fused_gram", "block_update"):
+            monkeypatch.setattr(importlib.import_module(f"repro.kernels.{name}.ops"),
+                                "resolve_dispatch", lambda op, use: (True, True))
+        a = suite_surrogate("audikw_1", scale=0.05)
+        ad = np.asarray(a.todense(), np.float64)
+        b = np.random.default_rng(11).standard_normal(a.shape[0])
+        tol = 1e-10
+        solver = ECGSolver.build(a, None, SolverConfig(
+            t=8, tol=tol, max_iters=500, tune="off",
+            kernel=KernelConfig(backend="pallas", ell_block=(16, 16))))
+        res = solver.solve(b)
+        assert bool(res.converged)
+        x = np.asarray(res.x, np.float64)
+        want = np.linalg.solve(ad, b)
+        # the recursive residual meets tol; in float64 the true one stays
+        # within 10x of it, and the error is bounded by cond(A) times that
+        assert np.linalg.norm(b - ad @ x) / np.linalg.norm(b) < 10 * tol
+        bound = np.linalg.cond(ad) * 10 * tol
+        assert np.linalg.norm(x - want) / np.linalg.norm(want) < bound

@@ -265,3 +265,48 @@ class TestIllConditionedGenerators:
         assert not np.array_equal(d, other)
         with pytest.raises(ValueError, match="decades"):
             scaled_laplace_2d(8, decades=0.0)
+
+
+_SURROGATE_DIGEST = r"""
+import hashlib, sys
+import numpy as np
+from repro.sparse.matrices import suite_surrogate, surrogate_graph
+
+gen = sys.argv[1]
+a = (suite_surrogate("audikw_1", scale=0.05) if gen == "suite_surrogate"
+     else surrogate_graph("audikw_1", scale=0.05)[0])
+h = hashlib.sha256()
+for arr in (a.indptr, a.indices, a.data):
+    h.update(np.asarray(arr).tobytes())
+print(h.hexdigest())
+"""
+
+
+class TestSurrogateSeed:
+    """The window shuffle of a Table-3 surrogate is seeded from its name by
+    a function that does not change between processes (Python salts the
+    hash of a ``str`` per process, by ``PYTHONHASHSEED``)."""
+
+    @pytest.mark.parametrize("gen", ["suite_surrogate", "surrogate_graph"])
+    def test_same_arrays_in_every_process(self, gen):
+        import os
+        import subprocess
+        import sys
+        from pathlib import Path
+
+        src = str(Path(__file__).resolve().parents[1] / "src")
+        digests = set()
+        for hash_seed in ("1", "2"):
+            env = dict(os.environ, PYTHONHASHSEED=hash_seed, JAX_PLATFORMS="cpu",
+                       PYTHONPATH=src)
+            out = subprocess.run([sys.executable, "-c", _SURROGATE_DIGEST, gen], env=env,
+                                 capture_output=True, text=True, timeout=300)
+            assert out.returncode == 0, out.stderr[-2000:]
+            digests.add(out.stdout.split()[-1])
+        assert len(digests) == 1
+
+    def test_seed_is_the_crc_of_the_name(self):
+        from repro.sparse.matrices import surrogate_perm_seed
+
+        assert surrogate_perm_seed("audikw_1") == 286_300_102
+        assert len({surrogate_perm_seed(n) for n in SUITE_MATRICES}) == len(SUITE_MATRICES)

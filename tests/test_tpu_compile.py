@@ -89,6 +89,29 @@ def test_ecg_tail_compiles(one_chip, chip_mode):
     _compile(ecg_tail_pallas, one_chip, *[((N, T), F32)] * 5, *[((T, T), F32)] * 3)
 
 
+#: the paper's Table 3 audikw_1 surrogate at scale 1.0: 243 x 243 elements of
+#: 16 rows, 59,049 block rows (461.3 lane groups), 944,784 rows (57.7 blocks
+#: of 16,384 block-vector rows): every kernel's last block is ragged
+NBR_RAGGED = 243 * 243
+
+
+@pytest.mark.parametrize("kernel", ["bsr_spmbv", "fused_gram", "ecg_tail"])
+def test_kernels_compile_at_ragged_sizes(one_chip, chip_mode, kernel):
+    from repro.kernels.block_update.kernel import ecg_tail_pallas
+    from repro.kernels.bsr_spmbv.kernel import LANES, bsr_spmbv_pallas
+    from repro.kernels.fused_gram.kernel import fused_gram_pallas
+
+    n = NBR_RAGGED * TILE
+    if kernel == "bsr_spmbv":
+        _compile(bsr_spmbv_pallas, one_chip,
+                 ((-(-NBR_RAGGED // LANES), KMAX * TILE, TILE, LANES), F32),
+                 ((NBR_RAGGED, KMAX), I32), ((n, T), F32))
+    elif kernel == "fused_gram":
+        _compile(fused_gram_pallas, one_chip, *[((n, T), F32)] * 4)
+    else:
+        _compile(ecg_tail_pallas, one_chip, *[((n, T), F32)] * 5, *[((T, T), F32)] * 3)
+
+
 def test_halo_pack_compiles(one_chip, chip_mode):
     from repro.kernels.halo_pack.kernel import halo_pack_pallas
 
@@ -132,6 +155,8 @@ def test_solve_program_names_its_stages(one_chip, chip_mode, monkeypatch):
     fn, lifted = solver._jit(T, "fresh")._entry((vec, vec))
     consts = [jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip) for x in lifted]
     hlo = fn.lower(consts, vec, vec).compile().as_text()
+    # the gather of V is named inside the SpMBV
+    assert re.search(r'op_name="[^"]*/ecg\.spmbv/[^"]*ecg\.gather/', hlo)
     calls = {}
     for line in hlo.splitlines():
         if 'custom_call_target="tpu_custom_call"' in line:
