@@ -405,8 +405,8 @@ class TestSolvePacked:
 
 
 class TestTable3Surrogate:
-    """The paper's Table 3 audikw_1, as the repo's window-shuffled surrogate
-    at a small scale (144 block rows: a ragged last lane group), solved
+    """The repo's window-shuffled surrogate of Table 3's audikw_1 at a small
+    scale (144 block rows: a ragged last lane group), solved
     through ``ECGSolver.build``/``solve`` with every kernel in Pallas
     interpret mode, against a plain float64 solve of the same CSR."""
 

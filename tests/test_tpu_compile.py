@@ -89,7 +89,7 @@ def test_ecg_tail_compiles(one_chip, chip_mode):
     _compile(ecg_tail_pallas, one_chip, *[((N, T), F32)] * 5, *[((T, T), F32)] * 3)
 
 
-#: the paper's Table 3 audikw_1 surrogate at scale 1.0: 243 x 243 elements of
+#: the repo's audikw_1 surrogate at scale 1.0: 243 x 243 elements of
 #: 16 rows, 59,049 block rows (461.3 lane groups), 944,784 rows (57.7 blocks
 #: of 16,384 block-vector rows): every kernel's last block is ragged
 NBR_RAGGED = 243 * 243
