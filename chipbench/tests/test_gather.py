@@ -15,7 +15,7 @@ HloModule jit_run, entry_computation_layout={()->f32[8]{0}}
 ENTRY %main.1 () -> f32[8] {
   %copy.1 = f32[8]{0} copy(), metadata={op_name="jit(run)/while/body/ecg.spmbv/jit(bsr_spmbv_pallas)/ecg.gather/transpose"}
   %fusion.2 = f32[8]{0} fusion(), kind=kLoop, calls=%f, metadata={op_name="jit(run)/while/body/ecg.spmbv/jit(bsr_spmbv_pallas)/ecg.gather/gather"}
-  %bsr_spmbv.3 = f32[640,16,8,128]{3,2,1,0} custom-call(%fusion.2), custom_call_target="tpu_custom_call", operand_layout_constraints={f32[640,80,16,128]{3,2,1,0}, f32[640,80,8,128]{3,2,1,0}}, metadata={op_name="jit(run)/while/body/ecg.spmbv/jit(bsr_spmbv_pallas)/bsr_spmbv/pallas_call"}
+  %bsr_spmbv.3 = f32[462,16,8,128]{3,2,1,0} custom-call(%fusion.2), custom_call_target="tpu_custom_call", operand_layout_constraints={f32[462,80,16,128]{3,2,1,0}, f32[462,80,8,128]{3,2,1,0}}, metadata={op_name="jit(run)/while/body/ecg.spmbv/jit(bsr_spmbv_pallas)/bsr_spmbv/pallas_call"}
   %copy.4 = f32[8]{0} copy(), metadata={op_name="jit(run)/while/body/ecg.spmbv/jit(bsr_spmbv_pallas)/transpose"}
   ROOT %add.5 = f32[8]{0} add(), metadata={op_name="jit(run)/while/body/ecg.gatherx/add"}
 }
@@ -33,7 +33,7 @@ OPS = [tr.Op(name, float(start), float(dur), "jit_run") for name, start, dur in 
 
 def readings(hlo_text):
     bench = Bench(ROOT)
-    cell = bench.cell("dg_ex21_1chip.rhs_stream")
+    cell = bench.cell("audikw1_1chip.rhs_stream")
     solves = [harness.Solve(None, None, 2, True, 1.0)]
     return harness.Readings(
         cell=cell, config=bench.config(cell["config"]), traffic={}, solves=solves,
@@ -55,10 +55,10 @@ def test_gather_events_and_time():
 
 
 def test_gather_bytes_and_roofline():
-    # the DG operator on one chip: 640 lane groups of 128 block rows,
+    # the audikw_1 surrogate on one chip: 462 lane groups of 128 block rows,
     # 5 tiles of 16 columns, t = 8: ids, then V's gathered rows read and written
-    per_call = gather.from_kernel_shapes((640, 80, 8, 128), bc=16)
-    assert per_call == 81920 * 5 * 4 + 2 * 81920 * 5 * 16 * 8 * 4
+    per_call = gather.from_kernel_shapes((462, 80, 8, 128), bc=16)
+    assert per_call == 59136 * 5 * 4 + 2 * 59136 * 5 * 16 * 8 * 4
     assert gather.gather_bytes(2, 3, 2, 4) == 2 * 3 * 4 + 2 * 2 * 3 * 2 * 4 * 4
     want = 100.0 * 2 * per_call / 819e9 / (800e-9)
     assert read("gather_hbm_roofline", readings(HLO)) == pytest.approx(want)
