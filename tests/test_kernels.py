@@ -94,6 +94,79 @@ class TestBsrSpmbv:
             )
 
 
+    @pytest.mark.parametrize("case,vregs", [
+        ("whole_groups", True),    # 256 block rows: two whole lane groups
+        ("ragged_groups", True),   # 300 block rows: the third group ragged
+        ("zero_slots", True),      # irregular tiles per row: zero-padded slots
+        ("float64", True),         # the CPU oracle's precision
+        ("t4", False),             # bc·t = 64: the lane path
+        ("bfloat16", False),       # two rows per sublane: the lane path
+    ])
+    def test_vreg_path_against_lane_path(self, rng, case, vregs):
+        """16x16 tiles at t = 8 take the vreg path (relayouts in VMEM,
+        gather in grid order, W written as Wᵀ); it gives the lane path's
+        W bit for bit, and the oracle's.  Other shapes keep the lane path."""
+        from repro.kernels.bsr_spmbv import kernel as K
+        from repro.kernels.bsr_spmbv.ops import block_ell_arrays
+
+        a = {"whole_groups": dg_laplace_2d((16, 16), block=16),
+             "ragged_groups": dg_laplace_2d((20, 15), block=16),
+             "zero_slots": random_spd(800, density=0.01, seed=5)}.get(
+                 case, dg_laplace_2d((8, 6), block=16))
+        blocks, indices, m_pad, meta, _ = block_ell_arrays(a, 16, 16)
+        dtype = {"float64": jnp.float64, "bfloat16": jnp.bfloat16}.get(case, jnp.float32)
+        t = 4 if case == "t4" else 8
+        blocks = blocks.astype(dtype)
+        v = jnp.asarray(rng.standard_normal((m_pad, t)), dtype)
+        if case == "zero_slots":
+            assert meta["pad_hist"][-1] < meta["nbr"]
+        nbg, kbc = blocks.shape[:2]
+        nbr = indices.shape[0]
+        assert K._on_vregs(16, 16, t, dtype) == vregs
+        got = bsr_spmbv_pallas(blocks, indices, v, interpret=True)
+        lane = K._product(
+            blocks, K._gather_lanes(v, indices, bc=16, nbg=nbg, vregs=False, interpret=True),
+            nbr=nbr, vregs=False, interpret=True)
+        assert got.shape == lane.shape == (nbr * 16, t) and got.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(got, np.float64), np.asarray(lane, np.float64))
+        want = bsr_spmbv_ref(blocks, indices, v)
+        tol = tol_for(dtype) if dtype != jnp.float64 else dict(rtol=1e-12, atol=1e-12)
+        np.testing.assert_allclose(np.asarray(got, np.float64), np.asarray(want, np.float64), **tol)
+
+    def test_ell_spmbv_over_own_and_halo_rows(self, rng, monkeypatch):
+        """The distributed executor's local product, ``_ell_spmbv`` over a
+        rank's padded [own ‖ halo] block vector (the 2x2 cell's path, 16x16
+        tiles at t = 8: the vreg path), equals the CSR rows' product."""
+        import importlib
+
+        from repro.sparse.partition import partition_csr, rebased_local_csr
+        from repro.sparse.spmbv import DistributedSpMBV, _stack_block_ell
+
+        monkeypatch.setattr(importlib.import_module("repro.kernels.bsr_spmbv.ops"),
+                            "resolve_dispatch", lambda op, use: (True, True))
+        a = dg_laplace_2d((12, 10), block=16)
+        n = a.shape[0]
+        ad = np.asarray(a.todense(), np.float64)
+        v = rng.standard_normal((n, 8))
+        pm = partition_csr(a, 2)
+        rmax = pm.part.max_local_rows
+        halo_max = max(len(h) for h in pm.halo_sources)
+        assert halo_max > 0
+        rebased = rebased_local_csr(pm)
+        per_rank = [(np.arange(n_local), ptr, ix, dat) for ptr, ix, dat, n_local in rebased]
+        blocks, idx, _ = _stack_block_ell(per_rank, rmax, rmax + halo_max, 16, 16, np.float64)
+        for r in range(2):
+            lo, hi = pm.part.local_range(r)
+            own = np.zeros((rmax, 8))
+            own[: hi - lo] = v[lo:hi]
+            halo = np.zeros((halo_max, 8))
+            halo[: len(pm.halo_sources[r])] = v[pm.halo_sources[r]]
+            xfull = jnp.asarray(np.concatenate([own, halo]))
+            w = DistributedSpMBV._ell_spmbv(None, xfull, jnp.asarray(blocks[r]), jnp.asarray(idx[r]))
+            np.testing.assert_allclose(np.asarray(w)[: hi - lo], ad[lo:hi] @ v,
+                                       rtol=1e-12, atol=1e-12)
+
+
 class TestFusedGram:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("n,t,block_rows", [(64, 4, 16), (200, 5, 64), (1000, 20, 256), (37, 3, 8)])

@@ -613,3 +613,43 @@ def test_loop_body_carries_stage_scopes(lowered_schemes, method, devices):
         want_kernels |= {"halo_pack", "halo_unpack"}
     assert kernels == want_kernels
     assert (collectives > 0) == (devices > 1)
+
+
+@pytest.mark.parametrize("nbr", [81920, 59049])
+def test_spmbv_kernel_operands_keep_the_yardstick_shapes(monkeypatch, nbr):
+    """The benchmark counts the SpMBV's and the gather's bytes from the
+    first two operand shapes of the one custom call whose ``op_name`` names
+    ``bsr_spmbv`` (``chipbench/metrics/{spmbv,gather}_hbm_roofline.py``).
+    The solve's SpMBV (the ``make_block_ell_apply`` closure), lowered for
+    the TPU at DG's and the surrogate's sizes with no chip, still passes
+    tiles ``(nbg, kmax·bc, br, 128)`` and gathered V ``(nbg, kmax·bc, t,
+    128)``, t at index 2, so both counts hold."""
+    import importlib
+
+    from chipbench import gather, spmbv_bytes, trace as tr
+    from repro.kernels.bsr_spmbv import ops
+
+    # the kernel as the chip runs it: this host's backend is the CPU
+    monkeypatch.setattr(importlib.import_module("repro.kernels.bsr_spmbv.ops"),
+                        "resolve_dispatch", lambda op, use: (True, False))
+    nbg, kmax, br, bc, t = -(-nbr // 128), 5, 16, 16, 8
+
+    def solve_spmbv(blocks, indices, v):
+        return ops.make_block_ell_apply_from_arrays(blocks, indices, nbr * bc, nbr * bc)(v)
+
+    args = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        ((nbg, kmax * bc, br, 128), jnp.float32), ((nbr, kmax), jnp.int32),
+        ((nbr * bc, t), jnp.float32))]
+    with jax.enable_x64(False):  # the chip's precision
+        lowered = jax.jit(solve_spmbv).trace(*args).lower(lowering_platforms=("tpu",))
+    hlo = tr.HloIndex(lowered.as_text(dialect="hlo", debug_info=True))
+    (kernel,) = [i for m in hlo.by_module.values() for i in m.values()
+                 if tr.is_pallas(i, "bsr_spmbv")]
+    tiles, gathered = kernel.shapes()[:2]
+    assert tiles == (nbg, kmax * bc, br, 128)
+    assert gathered == (nbg, kmax * bc, t, 128)
+    nbr_pad = nbg * 128
+    assert (spmbv_bytes.from_kernel_shapes(tiles, gathered, bc)
+            == (spmbv_bytes.spmbv_flops(nbr_pad, kmax, br, bc, t),
+                spmbv_bytes.spmbv_bytes(nbr_pad, kmax, br, bc, t)))
+    assert gather.from_kernel_shapes(gathered, bc) == gather.gather_bytes(nbr_pad, kmax, bc, t)

@@ -95,10 +95,10 @@ def test_ecg_tail_compiles(one_chip, chip_mode):
 NBR_RAGGED = 243 * 243
 
 
-@pytest.mark.parametrize("kernel", ["bsr_spmbv", "fused_gram", "ecg_tail"])
+@pytest.mark.parametrize("kernel", ["bsr_spmbv", "fused_gram", "ecg_tail", "v_rows"])
 def test_kernels_compile_at_ragged_sizes(one_chip, chip_mode, kernel):
     from repro.kernels.block_update.kernel import ecg_tail_pallas
-    from repro.kernels.bsr_spmbv.kernel import LANES, bsr_spmbv_pallas
+    from repro.kernels.bsr_spmbv.kernel import LANES, _v_rows, bsr_spmbv_pallas
     from repro.kernels.fused_gram.kernel import fused_gram_pallas
 
     n = NBR_RAGGED * TILE
@@ -106,6 +106,8 @@ def test_kernels_compile_at_ragged_sizes(one_chip, chip_mode, kernel):
         _compile(bsr_spmbv_pallas, one_chip,
                  ((-(-NBR_RAGGED // LANES), KMAX * TILE, TILE, LANES), F32),
                  ((NBR_RAGGED, KMAX), I32), ((n, T), F32))
+    elif kernel == "v_rows":
+        _compile(lambda vt: _v_rows(vt, False), one_chip, ((T, n), F32))
     elif kernel == "fused_gram":
         _compile(fused_gram_pallas, one_chip, *[((n, T), F32)] * 4)
     else:
@@ -163,8 +165,11 @@ def test_solve_program_names_its_stages(one_chip, chip_mode, monkeypatch):
             op_name = re.search(r'op_name="([^"]*)"', line)[1]
             if "/while/body/" in op_name:
                 calls[op_name.split("/")[-2]] = op_name.split("/")
-    assert set(calls) == {"bsr_spmbv", "fused_gram", "ecg_tail"}
-    assert "ecg.spmbv" in calls["bsr_spmbv"] and "jit(bsr_spmbv_pallas)" in calls["bsr_spmbv"]
+    assert set(calls) == {"bsr_spmbv", "v_rows", "fused_gram", "ecg_tail"}
+    assert "ecg.spmbv" in calls["bsr_spmbv"] and "jit(_product)" in calls["bsr_spmbv"]
+    # the relayout of V is part of the gather; only the product's op_name
+    # names the kernel, which the benchmark's readers match on
+    assert "ecg.gather" in calls["v_rows"] and "bsr_spmbv" not in "/".join(calls["v_rows"])
     assert "ecg.gram" in calls["fused_gram"]
     assert "ecg.update" in calls["ecg_tail"]
 
@@ -217,3 +222,49 @@ def test_block_vector_kernels_take_xla_layout(one_chip, chip_mode):
                 assert oop in ("bitcast", "parameter", "get-tuple-element") or same, oline
     assert sorted(k.split(".")[0] for k in kernels) == ["ecg_tail", "fused_gram"]
     assert [len(v) for k, v in sorted(kernels.items())] == [8, 4]
+
+
+def _computation(hlo, name):
+    """The text of the computation ``name`` of the compiled program."""
+    start = hlo.index(f"%{name} (")
+    return hlo[start:hlo.index("\n}", start)]
+
+
+@pytest.mark.parametrize("nbr", [NBR, NBR_RAGGED])
+def test_spmbv_relayouts_stay_in_vmem(one_chip, chip_mode, nbr):
+    """Ten steps whose (N, T) carry goes through ``bsr_spmbv_pallas``, at
+    DG's size and at the surrogate's ragged one, compile with no relayout
+    of V or W through HBM: no program value has a minor dimension of 8
+    or 16 under a (8, 128) tile (``f32[8,81920,16]``, ``f32[640,128,16,8]``),
+    no 1-D loop-reshape of a block vector is left, and the kernel's
+    gathered operand is the gather's result through bitcasts only, the
+    gather reading the rows ``v_rows`` wrote from Vᵀ."""
+    import re
+
+    from repro.kernels.bsr_spmbv.kernel import LANES, bsr_spmbv_pallas
+
+    n = nbr * TILE
+
+    def run(blocks, idx, v):
+        return jax.lax.fori_loop(
+            0, 10, lambda i, v: bsr_spmbv_pallas(blocks, idx, v) * 0.5 + v, v)
+
+    hlo = _compile(run, one_chip, ((-(-nbr // LANES), KMAX * TILE, TILE, LANES), F32),
+                   ((nbr, KMAX), I32), ((n, T), F32))
+    ins = _instructions(hlo)
+    for ty, _, _, line in ins.values():
+        assert f"[{n * T}]" not in ty, line
+        for dims, layout in re.findall(r"\[([\d,]*)\]\{([\d,]+):T\(8,128\)", line):
+            dims = [int(d) for d in dims.split(",")]
+            minor = dims[int(layout.split(",")[0])]
+            assert minor not in (TILE, T), line
+    (kernel,) = [k for k, (_, op, _, line) in ins.items()
+                 if op == "custom-call" and k.startswith("bsr_spmbv")]
+    src = ins[kernel][2][1]
+    while ins[src][1] == "bitcast":
+        src = ins[src][2][0]
+    assert ins[src][1] in ("gather", "fusion"), ins[src][3]
+    if ins[src][1] == "fusion":
+        assert " gather(" in _computation(hlo, re.search(r"calls=%([\w.\-]+)", ins[src][3])[1])
+    assert ins[ins[src][2][0]][0].startswith(f"f32[{-(-n // TILE)},{LANES}]")
+    assert ins[src][2][0].startswith("v_rows")
