@@ -93,7 +93,6 @@ class TestBsrSpmbv:
                 np.asarray(got)[: len(rows)], ad[rows] @ v, rtol=1e-12, atol=1e-12
             )
 
-
     @pytest.mark.parametrize("case,vregs", [
         ("whole_groups", True),    # 256 block rows: two whole lane groups
         ("ragged_groups", True),   # 300 block rows: the third group ragged
