@@ -154,6 +154,13 @@ def _gather_lanes(v, indices, *, bc: int, nbg: int, vregs: bool, interpret: bool
         return vg.reshape(nbg, kmax * bc, t, LANES)
 
 
+def _madd(rows, a, v):
+    """One tile column's multiply-adds, shared by both kernels:
+    ``rows[i] + a[i] * v`` for each row i of the (br, L) tile slab ``a``
+    (one lane row, broadcast down the sublanes of the (t, L) slab ``v``)."""
+    return tuple(r + a[i : i + 1, :] * v for i, r in enumerate(rows))
+
+
 def _kernel(a_ref, v_ref, out_ref):
     # a_ref (1, kbc, br, L) operator slab; v_ref (1, kbc, t, L) gathered V;
     # out_ref (1, br, t, L): out[i, j, l] = sum_kc a[kc, i, l] * v[kc, j, l]
@@ -162,9 +169,7 @@ def _kernel(a_ref, v_ref, out_ref):
     acc = _acc_dtype(out_ref.dtype)
 
     def step(kc, rows):
-        a = a_ref[0, kc].astype(acc)  # (br, L)
-        v = v_ref[0, kc].astype(acc)  # (t, L)
-        return tuple(rows[i] + a[i : i + 1, :] * v for i in range(br))
+        return _madd(rows, a_ref[0, kc].astype(acc), v_ref[0, kc].astype(acc))
 
     zero = jnp.zeros((t, lanes), acc)
     rows = jax.lax.fori_loop(0, kbc, step, (zero,) * br)
@@ -175,8 +180,8 @@ def _kernel(a_ref, v_ref, out_ref):
 def _vreg_kernel(a_ref, v_ref, out_ref, buf_ref):
     # a_ref (1, kbc, br, L) operator slab; v_ref (1, kbc, t, L) gathered V,
     # slot k's bc slabs the (L, bc·t) rows of its block rows; out_ref
-    # (t, L·br) the group's columns of Wᵀ; buf_ref (br·t, L).  The same
-    # multiply-adds as ``_kernel``, in the same kc order.
+    # (t, L·br) the group's columns of Wᵀ; buf_ref (br·t, L).  ``_kernel``'s
+    # multiply-adds (``_madd``), in the same kc order.
     kbc, br, lanes = a_ref.shape[1:]
     t = v_ref.shape[2]
     bc = lanes // t
@@ -186,9 +191,7 @@ def _vreg_kernel(a_ref, v_ref, out_ref, buf_ref):
         # (bc·t, L): row c·t + j is column j of the slot's tile column c
         vt = v_ref[0, pl.ds(k * bc, bc)].reshape(lanes, bc * t).T.astype(acc)
         for c in range(bc):
-            a = a_ref[0, k * bc + c].astype(acc)  # (br, L)
-            v = vt[c * t:(c + 1) * t]  # (t, L)
-            rows = tuple(rows[i] + a[i : i + 1, :] * v for i in range(br))
+            rows = _madd(rows, a_ref[0, k * bc + c].astype(acc), vt[c * t:(c + 1) * t])
         return rows
 
     zero = jnp.zeros((t, lanes), acc)
