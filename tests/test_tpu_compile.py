@@ -230,16 +230,44 @@ def _computation(hlo, name):
     return hlo[start:hlo.index("\n}", start)]
 
 
-def _assert_spmbv_in_vmem(hlo, n, nbc):
-    """No relayout of V or W through HBM in the compiled program: no value
-    has a minor dimension of 8 or 16 under a (8, 128) tile, no 1-D
-    loop-reshape of an (n, T) block vector is left, and the kernel's
-    gathered operand is the gather's result through bitcasts only, the
-    gather reading the ``(nbc, LANES)`` rows ``v_rows`` wrote from Vᵀ."""
+@pytest.mark.parametrize("nbr,halo", [
+    pytest.param(NBR, 0, id=str(NBR)),
+    pytest.param(NBR_RAGGED, 0, id=str(NBR_RAGGED)),
+    pytest.param(RMAX // TILE, HALO + 1, id="rank_with_halo"),
+])
+def test_spmbv_relayouts_stay_in_vmem(one_chip, chip_mode, monkeypatch, nbr, halo):
+    """Ten steps whose (n, T) carry goes through ``bsr_spmbv_pallas``, at
+    DG's size, at the surrogate's ragged one, and as one rank of the 2x2
+    cell runs it (``DistributedSpMBV._ell_spmbv`` over the carry joined to
+    a halo of HALO + 1 rows and padded to whole tiles: 20,993 block
+    columns, a ragged number of lane groups), compile with no relayout of
+    V or W through HBM: no program value has a minor dimension of 8 or 16
+    under a (8, 128) tile (``f32[8,81920,16]``, ``f32[640,128,16,8]``), no
+    1-D loop-reshape of a block vector is left, and the kernel's gathered
+    operand is the gather's result through bitcasts only, the gather
+    reading the rows ``v_rows`` wrote from Vᵀ."""
+    import importlib
     import re
 
-    from repro.kernels.bsr_spmbv.kernel import LANES
+    from repro.kernels.bsr_spmbv.kernel import LANES, bsr_spmbv_pallas
+    from repro.sparse.spmbv import DistributedSpMBV
 
+    # the kernel as the chip runs it: this host's backend is the CPU
+    monkeypatch.setattr(importlib.import_module("repro.kernels.bsr_spmbv.ops"),
+                        "resolve_dispatch", lambda op, use: (True, False))
+    n = nbr * TILE
+
+    def product(blocks, idx, v):
+        if not halo:
+            return bsr_spmbv_pallas(blocks, idx, v)
+        xfull = jnp.concatenate([v, v[-halo:] * 0.25], axis=0)
+        return DistributedSpMBV._ell_spmbv(None, xfull, blocks, idx)[:n]
+
+    def run(blocks, idx, v):
+        return jax.lax.fori_loop(0, 10, lambda i, v: product(blocks, idx, v) * 0.5 + v, v)
+
+    hlo = _compile(run, one_chip, ((-(-nbr // LANES), KMAX * TILE, TILE, LANES), F32),
+                   ((nbr, KMAX), I32), ((n, T), F32))
     ins = _instructions(hlo)
     for ty, _, _, line in ins.values():
         assert f"[{n * T}]" not in ty, line
@@ -255,55 +283,5 @@ def _assert_spmbv_in_vmem(hlo, n, nbc):
     assert ins[src][1] in ("gather", "fusion"), ins[src][3]
     if ins[src][1] == "fusion":
         assert " gather(" in _computation(hlo, re.search(r"calls=%([\w.\-]+)", ins[src][3])[1])
-    assert ins[ins[src][2][0]][0].startswith(f"f32[{nbc},{LANES}]")
+    assert ins[ins[src][2][0]][0].startswith(f"f32[{-(-(n + halo) // TILE)},{LANES}]")
     assert ins[src][2][0].startswith("v_rows")
-
-
-@pytest.mark.parametrize("nbr", [NBR, NBR_RAGGED])
-def test_spmbv_relayouts_stay_in_vmem(one_chip, chip_mode, nbr):
-    """Ten steps whose (N, T) carry goes through ``bsr_spmbv_pallas``, at
-    DG's size and at the surrogate's ragged one, compile with no relayout
-    of V or W through HBM: no program value has a minor dimension of 8
-    or 16 under a (8, 128) tile (``f32[8,81920,16]``, ``f32[640,128,16,8]``),
-    no 1-D loop-reshape of a block vector is left, and the kernel's
-    gathered operand is the gather's result through bitcasts only, the
-    gather reading the rows ``v_rows`` wrote from Vᵀ."""
-    from repro.kernels.bsr_spmbv.kernel import LANES, bsr_spmbv_pallas
-
-    n = nbr * TILE
-
-    def run(blocks, idx, v):
-        return jax.lax.fori_loop(
-            0, 10, lambda i, v: bsr_spmbv_pallas(blocks, idx, v) * 0.5 + v, v)
-
-    hlo = _compile(run, one_chip, ((-(-nbr // LANES), KMAX * TILE, TILE, LANES), F32),
-                   ((nbr, KMAX), I32), ((n, T), F32))
-    _assert_spmbv_in_vmem(hlo, n, -(-n // TILE))
-
-
-def test_distributed_local_spmbv_relayouts_stay_in_vmem(one_chip, chip_mode, monkeypatch):
-    """One rank's local product of the 2x2 cell, as ``DistributedSpMBV``
-    runs it: ten steps whose (RMAX, T) carry is joined to a halo of
-    HALO + 1 rows and padded to whole tiles (20,993 block columns, a ragged
-    number of lane groups) before ``_ell_spmbv``, compile with the same
-    relayouts in VMEM as the one-chip product."""
-    import importlib
-
-    from repro.kernels.bsr_spmbv.kernel import LANES
-    from repro.sparse.spmbv import DistributedSpMBV
-
-    monkeypatch.setattr(importlib.import_module("repro.kernels.bsr_spmbv.ops"),
-                        "resolve_dispatch", lambda op, use: (True, False))
-    nbr, halo = RMAX // TILE, HALO + 1
-
-    def run(blocks, idx, v):
-        def step(i, v):
-            xfull = jnp.concatenate([v, v[-halo:] * 0.25], axis=0)
-            w = DistributedSpMBV._ell_spmbv(None, xfull, blocks, idx)[:RMAX]
-            return w * 0.5 + v
-
-        return jax.lax.fori_loop(0, 10, step, v)
-
-    hlo = _compile(run, one_chip, ((-(-nbr // LANES), KMAX * TILE, TILE, LANES), F32),
-                   ((nbr, KMAX), I32), ((RMAX, T), F32))
-    _assert_spmbv_in_vmem(hlo, RMAX, -(-(RMAX + halo) // TILE))
