@@ -134,12 +134,13 @@ def _gather_lanes(v, indices, *, bc: int, nbg: int, vregs: bool, interpret: bool
     and meet zero tiles).
 
     On ``vregs`` (:func:`_on_vregs`): ``v_rows`` builds the rows from Vᵀ in
-    VMEM, the ids are put in grid order (a small int32 transpose of loop
-    constants), and the gather's ``(nbg, kmax, LANES, bc·t)`` result is the
-    operand by a bitcast: slot k's ``bc`` (t, LANES) slabs hold the rows of
-    its LANES block rows, one row per sublane.  Otherwise the rows are
-    spelled from Vᵀ and the gathered rows transposed onto the lanes by
-    XLA, so slab ``k·bc + c`` holds column c of slot k, ``(t, LANES)``."""
+    VMEM, the ids are put in grid order (XLA keeps them column-major, so
+    no more than a bitcast), and the gather's ``(nbg, kmax, LANES, bc·t)``
+    result is the operand by a bitcast: slot k's ``bc`` (t, LANES) slabs
+    hold the rows of its LANES block rows, one row per sublane.  Otherwise
+    the rows are spelled from Vᵀ and the gathered rows transposed onto the
+    lanes by XLA, so slab ``k·bc + c`` holds column c of slot k,
+    ``(t, LANES)``."""
     nbr, kmax = indices.shape
     t = v.shape[1]
     with jax.named_scope(GATHER):

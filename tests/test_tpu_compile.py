@@ -29,15 +29,30 @@ HALO = 8192              # halo slots of one exchange phase
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     if importlib.util.find_spec("libtpu") is None:
         pytest.skip("the TPU compiler (libtpu) is not installed")
-    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    return SingleDeviceSharding(topo.devices[0])
+    return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_2x2.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(v5e_2x2):
+    """The host's four chips as one mesh axis ``r``, each holding a row
+    block of every argument."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    return NamedSharding(Mesh(np.array(v5e_2x2.devices), ("r",)), PartitionSpec("r"))
 
 
 @pytest.fixture
@@ -230,17 +245,19 @@ def _computation(hlo, name):
     return hlo[start:hlo.index("\n}", start)]
 
 
-@pytest.mark.parametrize("nbr,halo", [
-    pytest.param(NBR, 0, id=str(NBR)),
-    pytest.param(NBR_RAGGED, 0, id=str(NBR_RAGGED)),
-    pytest.param(RMAX // TILE, HALO + 1, id="rank_with_halo"),
+@pytest.mark.parametrize("nbr,halo,ranks", [
+    pytest.param(NBR, 0, 1, id=str(NBR)),
+    pytest.param(NBR_RAGGED, 0, 1, id=str(NBR_RAGGED)),
+    pytest.param(RMAX // TILE, HALO + 1, 1, id="rank_with_halo"),
+    pytest.param(RMAX // TILE, HALO + 1, 4, id="ranks_on_2x2_mesh"),
 ])
-def test_spmbv_relayouts_stay_in_vmem(one_chip, chip_mode, monkeypatch, nbr, halo):
+def test_spmbv_relayouts_stay_in_vmem(request, chip_mode, monkeypatch, nbr, halo, ranks):
     """Ten steps whose (n, T) carry goes through ``bsr_spmbv_pallas``, at
     DG's size, at the surrogate's ragged one, and as one rank of the 2x2
     cell runs it (``DistributedSpMBV._ell_spmbv`` over the carry joined to
     a halo of HALO + 1 rows and padded to whole tiles: 20,993 block
-    columns, a ragged number of lane groups), compile with no relayout of
+    columns, a ragged number of lane groups; alone, and in a ``shard_map``
+    over the host's four chips), compile with no relayout of
     V or W through HBM: no program value has a minor dimension of 8 or 16
     under a (8, 128) tile (``f32[8,81920,16]``, ``f32[640,128,16,8]``), no
     1-D loop-reshape of a block vector is left, and the kernel's gathered
@@ -263,11 +280,17 @@ def test_spmbv_relayouts_stay_in_vmem(one_chip, chip_mode, monkeypatch, nbr, hal
         xfull = jnp.concatenate([v, v[-halo:] * 0.25], axis=0)
         return DistributedSpMBV._ell_spmbv(None, xfull, blocks, idx)[:n]
 
+    sharding = request.getfixturevalue("one_chip" if ranks == 1 else "four_chips")
+    if ranks > 1:
+        product = jax.shard_map(product, mesh=sharding.mesh, in_specs=sharding.spec,
+                                out_specs=sharding.spec, check_vma=False)
+
     def run(blocks, idx, v):
         return jax.lax.fori_loop(0, 10, lambda i, v: product(blocks, idx, v) * 0.5 + v, v)
 
-    hlo = _compile(run, one_chip, ((-(-nbr // LANES), KMAX * TILE, TILE, LANES), F32),
-                   ((nbr, KMAX), I32), ((n, T), F32))
+    hlo = _compile(run, sharding,
+                   ((ranks * -(-nbr // LANES), KMAX * TILE, TILE, LANES), F32),
+                   ((ranks * nbr, KMAX), I32), ((ranks * n, T), F32))
     ins = _instructions(hlo)
     for ty, _, _, line in ins.values():
         assert f"[{n * T}]" not in ty, line
